@@ -218,6 +218,14 @@ def bench_mesh_2d(steps: int) -> dict:
 
 
 def main(fast: bool = True):
+    import jax
+
+    if jax.default_backend() == "tpu":
+        # The scaling and mesh points start CPU child processes after this
+        # process took the chip: on a TPU host they would report CPU numbers
+        # as replica and mesh scaling.
+        raise SystemExit("fleet_bench measures CPU child processes; it "
+                         "refuses to run on a TPU host")
     if fast:
         num_queries, pumps, mesh_steps, repeats = 120, 6, 120, 3
         replica_counts = (1, 2, 3)

@@ -15,6 +15,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.experiments import jointdpm
 
 
@@ -53,6 +54,7 @@ def main(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (seconds instead of minutes)")

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.bayes import TrainConfig, make_exact_step, make_train_step
 from repro.checkpoint import manager as ckpt
 from repro.data import DataConfig, MarkovStream
@@ -94,4 +95,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

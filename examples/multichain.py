@@ -17,6 +17,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ScheduleConfig
 from repro.experiments import bayeslr
 
@@ -59,6 +60,7 @@ def main(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (seconds instead of minutes)")

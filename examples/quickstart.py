@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (
     RandomWalk,
     SubsampledMHConfig,
@@ -65,6 +66,7 @@ def main(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (seconds instead of minutes)")

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import ARCHS, reduce_config
 from repro.models import decode_step, init_params, prefill
 
@@ -70,4 +71,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -70,6 +70,7 @@ particle Gibbs). That is how stochvol and jointdpm ride this engine; see
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -676,12 +677,11 @@ class ChainEnsemble:
             fn = jax.vmap(one_chain)
             mesh = self._chain_mesh()
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 spec = P(self.chain_axis)
-                fn = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec, spec),
-                               out_specs=(spec,) * 5, check_rep=False)
+                fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec, spec),
+                                   out_specs=(spec,) * 5, check_vma=False)
             return fn(step_keys, theta, sampler, ctrl)
 
         return jax.jit(run_all, static_argnames=("num_steps",))
@@ -1068,6 +1068,28 @@ class ChainEnsemble:
         keys = self._per_chain_keys(key)
         return self._fold_keys_jit(keys, jnp.uint32(start), num_steps=num_steps)
 
+    def _runner(self):
+        """The jitted program :meth:`run` dispatches to."""
+        if self.transition is not None:
+            return self._run_composite_jit
+        if self.stepping == "masked":
+            return self._run_masked_jit
+        if self._shard_2d_request is not None:
+            # 2-d chains x data requests run the batched-transition scan (the
+            # only lock-step form whose rounds expose a shardable data axis);
+            # on a single device the same runner executes unsharded —
+            # bit-for-bit the vmapped scan when unfused.
+            return (self._run_lockstep_fused_jit if self._use_fused()
+                    else self._run_lockstep_batched_jit)
+        if (self.kernel == "subsampled" and self._use_fused()
+                and (self.fused_kernels == "always" or self._chain_mesh() is None)):
+            # The fused lock-step scan runs unsharded. An explicit "always"
+            # wins over the chain mesh (shard=True + "always" is rejected at
+            # construction); under "auto" with a mesh present, the vmapped
+            # scan keeps the multi-device fan-out instead.
+            return self._run_lockstep_fused_jit
+        return self._run_jit
+
     def run(self, key: jax.Array | None, state: EnsembleState, num_steps: int,
             *, step_keys: jax.Array | None = None):
         """Advance every chain ``num_steps`` transitions in one XLA program.
@@ -1091,42 +1113,29 @@ class ChainEnsemble:
                     f"step_keys must be a ({self.num_chains}, {num_steps}) key "
                     f"array, got leading shape {lead}"
                 )
-        mesh2 = self._mesh_2d
-        if self.transition is not None:
-            runner = self._run_composite_jit
-        elif self.stepping == "masked":
-            runner = self._run_masked_jit
-        elif self._shard_2d_request is not None:
-            # 2-d chains x data requests run the batched-transition scan (the
-            # only lock-step form whose rounds expose a shardable data axis);
-            # on a single device the same runner executes unsharded —
-            # bit-for-bit the vmapped scan when unfused.
-            runner = (self._run_lockstep_fused_jit if self._use_fused()
-                      else self._run_lockstep_batched_jit)
-        elif (self.kernel == "subsampled" and self._use_fused()
-              and (self.fused_kernels == "always" or self._chain_mesh() is None)):
-            # The fused lock-step scan runs unsharded. An explicit "always"
-            # wins over the chain mesh (shard=True + "always" is rejected at
-            # construction); under "auto" with a mesh present, the vmapped
-            # scan keeps the multi-device fan-out instead.
-            runner = self._run_lockstep_fused_jit
-        else:
-            runner = self._run_jit
-        if mesh2 is not None:
-            # Activate the logical-axis rules while tracing/running so the
-            # lc constraints in the round loop (and in the kernel-family
-            # registry's gathers) bind to this mesh.
-            with logical_axis_rules(mesh2):
-                theta, sampler, ctrl, samples, infos = runner(
-                    step_keys, state.theta, state.sampler_state, state.controller,
-                    num_steps=num_steps
-                )
-        else:
-            theta, sampler, ctrl, samples, infos = runner(
+        with self._mesh_rules():
+            theta, sampler, ctrl, samples, infos = self._runner()(
                 step_keys, state.theta, state.sampler_state, state.controller,
                 num_steps=num_steps
             )
         return EnsembleState(theta, sampler, ctrl), samples, infos
+
+    def lower(self, state: EnsembleState, num_steps: int, *, step_keys: jax.Array):
+        """Lower the program ``run(None, state, num_steps, step_keys=...)``
+        executes, without running it — ``.compile().as_text()`` shows what
+        the device runs (e.g. whether a Pallas kernel is in it)."""
+        with self._mesh_rules():
+            return self._runner().lower(
+                step_keys, state.theta, state.sampler_state, state.controller,
+                num_steps=num_steps
+            )
+
+    def _mesh_rules(self):
+        # Activate the logical-axis rules while tracing/running so the lc
+        # constraints in the round loop (and in the kernel-family registry's
+        # gathers and kernels) bind to the 2-d mesh.
+        mesh2 = self._mesh_2d
+        return logical_axis_rules(mesh2) if mesh2 is not None else contextlib.nullcontext()
 
     def run_timed(self, key: jax.Array, state: EnsembleState, num_steps: int,
                   block_every: int = 1, *, start_step: int = 0, on_block=None):

@@ -40,7 +40,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from ..distributed.sharding import lc
+from ..distributed.sharding import lc, shard_local
 from .target import PartitionedTarget
 
 Params = Any
@@ -232,14 +232,25 @@ def _logit_delta(data, w, w_p, idx):
     return ref.logit_delta_ref(_gather(x, idx, 1), _gather(y, idx, 0), w, w_p)
 
 
+_CHAINS = ("ensemble_chains",)
+_ROUND = ("ensemble_chains", "subsample")
+
+
+def _local_round(fn, args, logical):
+    """Evaluate a (K, m) round kernel on each device's (chains, subsample)
+    block — a Pallas kernel does not partition itself under the 2-d mesh."""
+    k, m = args[0].shape[:2]
+    return shard_local(fn, args, logical, (k, m), _ROUND)
+
+
 def _logit_ensemble_delta(data, w, w_p, idx):
     from ..kernels import ops
 
     x, y = data
     idx = _shard_round_idx(idx)
-    return _replicate_round(ops.batched_logit_delta(
-        _gather_sharded(x, idx, 1), _gather_sharded(y, idx, 0), w, w_p
-    ))
+    args = (_gather_sharded(x, idx, 1), _gather_sharded(y, idx, 0), w, w_p)
+    logical = (_ROUND + (None,), _ROUND, _CHAINS + (None,), _CHAINS + (None,))
+    return _replicate_round(_local_round(ops.batched_logit_delta, args, logical))
 
 
 def _ar1_loglik(data, params, idx):
@@ -262,7 +273,11 @@ def _ar1_ensemble_delta(data, params, params_p, idx):
 
     idx = _shard_round_idx(idx)
     xt, xp = (_gather_sharded(a, idx, 0) for a in data)
-    return _replicate_round(ops.batched_gaussian_ar1_delta(xt, xp, *params, *params_p))
+    args = (xt, xp, *params, *params_p)
+    logical = (_ROUND, _ROUND) + (_CHAINS,) * 4
+    return _replicate_round(
+        _local_round(ops.batched_gaussian_ar1_delta, args, logical)
+    )
 
 
 def _ce_loglik(data, table, idx):
@@ -288,9 +303,12 @@ def _ce_ensemble_delta(data, table, table_p, idx):
     h, targets = data
     idx = _shard_round_idx(idx)
     hg, tg = _gather_sharded(h, idx, 1), _gather_sharded(targets, idx, 0)
-    return _replicate_round(
-        ops.batched_fused_ce(hg, table_p, tg) - ops.batched_fused_ce(hg, table, tg)
-    )
+    tab = (None, None) if table.ndim == 2 else _CHAINS + (None, None)
+    return _replicate_round(_local_round(
+        lambda hg, tg, t, t_p: ops.batched_fused_ce(hg, t_p, tg)
+        - ops.batched_fused_ce(hg, t, tg),
+        (hg, tg, table, table_p), (_ROUND + (None,), _ROUND, tab, tab),
+    ))
 
 
 def _gm_loglik(data, theta, idx):

@@ -114,6 +114,27 @@ def lc(x: jax.Array, logical: Sequence[str | None]) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def shard_local(fn, args: Sequence[jax.Array], in_logical: Sequence[Sequence[str | None]],
+                out_shape: Sequence[int], out_logical: Sequence[str | None]) -> jax.Array:
+    """``fn(*args)``, run on each device's shard when a mesh context is active.
+
+    A Pallas TPU kernel is a Mosaic custom call, which the compiler does not
+    partition: inside a program sharded through :func:`lc` constraints it is
+    refused. Under an active mesh this wraps ``fn`` in ``jax.shard_map``,
+    resolving every argument's and the result's spec from logical axis names
+    exactly as :func:`lc` does, so the kernel sees its local block. A plain
+    call otherwise.
+    """
+    mesh, rules = _CTX.mesh, _CTX.rules
+    if mesh is None or len(mesh.devices.reshape(-1)) <= 1:
+        return fn(*args)
+    in_specs = tuple(resolve_spec(a.shape, names, mesh, rules)
+                     for a, names in zip(args, in_logical))
+    out_spec = resolve_spec(out_shape, out_logical, mesh, rules)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
+                         check_vma=False)(*args)
+
+
 def named_sharding(mesh: Mesh, shape: Sequence[int], logical: Sequence[str | None],
                    rules: dict | None = None) -> NamedSharding:
     rules = dict(DEFAULT_RULES, **(rules or {}))

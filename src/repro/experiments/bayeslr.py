@@ -192,15 +192,19 @@ def make_serving_workload(
     ens = ChainEnsemble(target, RandomWalk(sigma), num_chains, config=cfg,
                         stepping=stepping, schedule=schedule)
     make_queries = row_sampler(np.asarray(data.x_test))
+    # Full-f32 matmuls: a TPU's default precision would round the operands
+    # to bfloat16, and the served predictive is checked against an f64
+    # reference at rtol 1e-4.
+    logits = lambda w, xs: jnp.dot(xs, w, precision=jax.lax.Precision.HIGHEST)
     specs = {
         "predictive": QuerySpec(
-            fn=lambda w, xs: jax.nn.sigmoid(xs @ w),
+            fn=lambda w, xs: jax.nn.sigmoid(logits(w, xs)),
             aggregate="mean",
             make_queries=make_queries,
             name="predictive",
         ),
         "vote": QuerySpec(
-            fn=lambda w, xs: (xs @ w > 0).astype(jnp.float32),
+            fn=lambda w, xs: (logits(w, xs) > 0).astype(jnp.float32),
             aggregate="mean",
             make_queries=make_queries,
             name="vote",

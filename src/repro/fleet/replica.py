@@ -353,6 +353,14 @@ class ReplicaProcess:
         self._spawn()
 
     def _spawn(self) -> None:
+        if jax.default_backend() == "tpu":
+            # The parent holds the chip; a spawned child cannot load the TPU
+            # runtime, and JAX would then serve it from the CPU unannounced.
+            raise RuntimeError(
+                f"replica process {self.name!r} refused: this process holds "
+                "the TPU, so a spawned replica would silently run on the CPU. "
+                "Use in-process replicas (FleetConfig(transport='inproc'))."
+            )
         ctx = mp.get_context("spawn")
         self._conn, child = ctx.Pipe()
         self._proc = ctx.Process(

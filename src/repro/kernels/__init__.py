@@ -1,10 +1,16 @@
 """Pallas TPU kernels for the likelihood hot spots (+ jnp oracles).
 
-fused_ce                  — vocab-blocked per-token log-likelihood (online logsumexp)
-batched_fused_ce          — the (K, T) ensemble-batched form: one grid over chains
-logit_delta               — pair-fused BayesLR MH delta (x read once for theta, theta')
-batched_logit_delta       — the (K, m) ensemble-batched form of logit_delta: one
-                            fused pallas_call per multi-chain sequential-test round
+Per-section vectors move as lane-dense (1, tile) rows of (K, 1, m) arrays,
+the block layout a TPU accepts; interpret mode on CPU checks values, and
+tests/test_tpu_compile.py checks the layouts against the v5e compiler.
+
+batched_fused_ce          — vocab-blocked per-token log-likelihood (online
+                            logsumexp) over (K, T): one grid over chains
+fused_ce                  — its single-sequence (K = 1) case
+batched_logit_delta       — pair-fused BayesLR MH delta (x read once for theta,
+                            theta') over (K, m): one fused pallas_call per
+                            multi-chain sequential-test round
+logit_delta               — its single-chain (K = 1) case
 batched_gaussian_ar1_delta — the (K, m) AR(1) transition-factor delta (stochvol)
 batched_pgibbs_sweep      — fused particle-Gibbs sweep: all (K chains, S series,
                             P particles) advanced by ONE time-major scan, sharing

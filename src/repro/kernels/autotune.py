@@ -15,9 +15,15 @@ Scope and knobs:
   VMEM behaviour, so CPU runs stay deterministic and fast by default.
 * ``REPRO_AUTOTUNE_DIR`` relocates the cache (CI sets it to a workspace
   path and uploads the JSON as a build artifact); the default is
-  ``~/.cache/repro/autotune``.
+  ``<checkout>/.autotune`` — nothing outside the checkout is read or
+  written, and a missing cache only means the race is run again.
 * Shapes are bucketed to powers of two: one measurement covers the whole
   regime, and the compiled-kernel cache can't be flooded by ragged shapes.
+* Every candidate tile is a multiple of 128: the kernels move per-section
+  rows as lane-dense (1, tile) blocks, which a TPU accepts only then (or
+  when the tile spans the whole axis). A race in which no candidate runs
+  raises with the first failure instead of falling back to defaults that
+  would fail the same way.
 
 Consulted by :mod:`repro.kernels.ops` — explicit ``tile_*`` kwargs always
 win over the tuner, so call sites keep full control.
@@ -32,6 +38,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..compile_cache import CHECKOUT
 
 ENV_VAR = "REPRO_AUTOTUNE"
 DIR_ENV_VAR = "REPRO_AUTOTUNE_DIR"
@@ -74,9 +82,7 @@ def enabled() -> bool:
 
 
 def cache_dir() -> str:
-    return os.environ.get(DIR_ENV_VAR) or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro", "autotune"
-    )
+    return os.environ.get(DIR_ENV_VAR) or str(CHECKOUT / ".autotune")
 
 
 def _cache_path(backend: str) -> str:
@@ -194,15 +200,19 @@ def _benchmark(family: str, shape: tuple[int, ...], interpret: bool) -> dict:
     bucketed = tuple(_bucket(int(d)) for d in shape)
     args = _synth_inputs(family, bucketed)
     kernel = _kernel_fn(family)
-    timings = []
+    timings, failures = [], []
     for cand in CANDIDATES[family]:
         try:
             sec = _time_once(lambda: kernel(*args, interpret=interpret, **cand))
-        except Exception:  # candidate invalid on this backend: skip it
+        except Exception as e:  # noqa: BLE001 — candidate invalid here: skip it
+            failures.append(f"{cand}: {type(e).__name__}: {e}")
             continue
         timings.append((sec, cand))
     if not timings:
-        return {"tiles": dict(DEFAULT_TILES[family]), "us": None}
+        raise RuntimeError(
+            f"autotune: no {family} candidate tile compiled at shape "
+            f"{bucketed} on {jax.default_backend()}; first failure: {failures[0]}"
+        )
     timings.sort(key=lambda tc: tc[0])
     best_sec, best = timings[0]
     return {

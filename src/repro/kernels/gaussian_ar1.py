@@ -13,6 +13,11 @@ masking, and both sides of the MH ratio in one pass over the gathered
 (K, m) slabs (which stay outside the kernel, fused with the sampler's index
 production, exactly like :mod:`repro.kernels.batched_loglik`).
 
+Layout: the slabs travel as (K, 1, m) arrays, one lane-dense (1, tile_m)
+row per grid step (see :mod:`repro.kernels.batched_loglik`). The four
+per-chain scalars are scalar-prefetched into SMEM as one flat (4K,) vector
+and read by chain index, then broadcast against the row.
+
 Grid: (K, ceil(m / tile_m)). ``ref.batched_gaussian_ar1_delta_ref`` is the
 pure-jnp twin used for interpret-mode parity tests on CPU.
 """
@@ -23,13 +28,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(xt_ref, xp_ref, par_ref, out_ref):
-    xt = xt_ref[0].astype(jnp.float32)  # (tile_m,) gathered x_t of this chain
-    xp = xp_ref[0].astype(jnp.float32)  # (tile_m,) gathered x_{t-1}
-    par = par_ref[0]  # (4,): [phi, s2, phi', s2']
-    phi_c, s2_c, phi_p, s2_p = par[0], par[1], par[2], par[3]
+def _kernel(par_ref, xt_ref, xp_ref, out_ref):
+    base = 4 * pl.program_id(0)
+    xt = xt_ref[0].astype(jnp.float32)  # (1, tile_m) gathered x_t of this chain
+    xp = xp_ref[0].astype(jnp.float32)  # (1, tile_m) gathered x_{t-1}
+    row = lambda j: jnp.full(xt.shape, par_ref[base + j], jnp.float32)
+    phi_c, s2_c, phi_p, s2_p = row(0), row(1), row(2), row(3)
     s2_c = jnp.maximum(s2_c, 1e-12)
     s2_p = jnp.maximum(s2_p, 1e-12)
     lc = -0.5 * ((xt - phi_c * xp) ** 2 / s2_c + jnp.log(s2_c))
@@ -53,7 +60,8 @@ def batched_gaussian_ar1_delta(
 
     bfloat16 ``xt``/``xp`` slabs are streamed as-is (half the HBM bytes of
     the memory-bound gather path) and upcast to float32 inside the kernel;
-    any other dtype is cast to float32 up front as before.
+    any other dtype is cast to float32 up front as before. On a TPU
+    ``tile_m`` must be a multiple of 128 unless it covers all of m.
     """
     k, m = xt.shape
     if xt.dtype != jnp.bfloat16:
@@ -66,17 +74,17 @@ def batched_gaussian_ar1_delta(
         xp = jnp.pad(xp, ((0, 0), (0, pad)))
     par = jnp.stack(
         [phi_cur, s2_cur, phi_prop, s2_prop], axis=-1
-    ).astype(jnp.float32)  # (K, 4)
+    ).astype(jnp.float32).reshape(-1)  # (4K,): chain k at [4k, 4k + 4)
+    row = pl.BlockSpec((1, 1, tile_m), lambda i, j, par: (i, 0, j))
     out = pl.pallas_call(
         _kernel,
-        grid=(k, (m + pad) // tile_m),
-        in_specs=[
-            pl.BlockSpec((1, tile_m), lambda i, j: (i, j)),
-            pl.BlockSpec((1, tile_m), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 4), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_m), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((k, m + pad), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(k, (m + pad) // tile_m),
+            in_specs=[row, row],
+            out_specs=row,
+        ),
+        out_shape=jax.ShapeDtypeStruct((k, 1, m + pad), jnp.float32),
         interpret=interpret,
-    )(xt, xp, par)
-    return out[:, :m]
+    )(par, xt[:, None, :], xp[:, None, :])
+    return out[:, 0, :m]

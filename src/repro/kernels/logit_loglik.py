@@ -3,32 +3,22 @@
 The paper's own hot spot (Sec. 4.1): every sequential-test round evaluates
 l_i = log sig(y_i x_i.w') - log sig(y_i x_i.w) for a mini-batch. Evaluating
 theta and theta' separately reads the feature tile x twice; MH always needs
-the PAIR, so this kernel computes both dot products per x-tile read — the
+the PAIR, so the kernel computes both dot products per x-tile read — the
 data movement is halved versus two passes (a beyond-paper fusion enabled by
 the structure of the MH ratio; see DESIGN.md §6).
 
-Grid: (N/tile_n,). Per step: one (tile_n x D) @ (D x 2) MXU matmul, then the
-log-sigmoid deltas on the VPU.
+The single-chain form is the one-chain case of
+:func:`repro.kernels.batched_loglik.batched_logit_delta`: one kernel body,
+one TPU layout. Grid: (1, N/tile_n). Per step: one (2 x D) @ (D x tile_n)
+MXU matmul, then the log-sigmoid deltas on the VPU.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-
-def _kernel(x_ref, y_ref, w2_ref, out_ref):
-    x = x_ref[...]
-    w2 = w2_ref[...]  # (D, 2): [w_cur, w_prop]
-    z = jax.lax.dot_general(
-        x, w2, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (tile_n, 2)
-    y = y_ref[...].astype(jnp.float32)
-    lc = -jnp.logaddexp(0.0, -y * z[:, 0])
-    lp = -jnp.logaddexp(0.0, -y * z[:, 1])
-    out_ref[...] = lp - lc
+from .batched_loglik import batched_logit_delta
 
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
@@ -41,23 +31,7 @@ def logit_delta(
     tile_n: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    n, d = x.shape
-    tile_n = min(tile_n, n)
-    pad = (-n) % tile_n
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-        y = jnp.pad(y, (0, pad), constant_values=1.0)
-    w2 = jnp.stack([w_cur, w_prop], axis=-1)  # (D, 2)
-    out = pl.pallas_call(
-        _kernel,
-        grid=((n + pad) // tile_n,),
-        in_specs=[
-            pl.BlockSpec((tile_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((tile_n,), lambda i: (i,)),
-            pl.BlockSpec((d, 2), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n + pad,), jnp.float32),
-        interpret=interpret,
-    )(x, y, w2)
-    return out[:n]
+    return batched_logit_delta(
+        x[None], y[None], w_cur[None], w_prop[None],
+        tile_m=tile_n, interpret=interpret,
+    )[0]

@@ -7,19 +7,13 @@ from __future__ import annotations
 
 import jax
 
-
-def _axis_types_kw(n_axes: int) -> dict:
-    # jax < 0.5 has no AxisType / axis_types kwarg; Auto is its only behavior.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_mesh_for_devices(n_devices: int | None = None, model_parallel: int | None = None):
@@ -28,4 +22,4 @@ def make_mesh_for_devices(n_devices: int | None = None, model_parallel: int | No
     n = n_devices or len(jax.devices())
     mp = model_parallel or 1
     assert n % mp == 0
-    return jax.make_mesh((n // mp, mp), ("data", "model"), **_axis_types_kw(2))
+    return jax.make_mesh((n // mp, mp), ("data", "model"), axis_types=(_AUTO,) * 2)

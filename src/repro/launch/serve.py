@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import ARCHS
 
 POSTERIOR_WORKLOADS = ("bayeslr", "stochvol", "jointdpm", "ppl")
@@ -417,6 +418,13 @@ def _offline_reference(workload, spec, snap, xs) -> np.ndarray | None:
         w = np.asarray(jax.tree.leaves(snap.draws)[0])
         w = w.reshape(-1, w.shape[-1])  # (S, D)
         return bayeslr.predictive_mean_prob(w, np.asarray(xs))[-1]
+    if workload.name == "stochvol" and spec.name == "vol_quantile":
+        # Per-draw stationary log-vol scale in the draws' own float32 (as the
+        # served functional computes it), then float64 numpy quantiles.
+        phi = np.asarray(snap.draws["phi"]).reshape(-1)
+        s2 = np.clip(np.asarray(snap.draws["sigma2"]).reshape(-1), 1e-12, None)
+        vol = np.sqrt(s2 / np.clip(1.0 - phi ** 2, 1e-6, None)).astype(np.float64)
+        return np.quantile(vol, np.clip(np.asarray(xs, np.float64), 0.0, 1.0))
     return None
 
 
@@ -553,7 +561,7 @@ def serve_posterior(args) -> int:
             return 1
         parity = f"ok(max|delta|={err:.2g})"
         print(f"  parity: served {workload.default_class} == offline "
-              f"predictive from the same draws ({parity})")
+              f"{spec.name} from the same draws ({parity})")
 
     if args.ckpt_dir:
         path = pool.save(args.ckpt_dir)
@@ -1217,6 +1225,7 @@ _LM_ONLY_FLAGS = ("arch", "reduced", "batch", "prompt_len", "gen_len",
 def main(argv=None) -> None:
     parser = build_parser()
     args = parser.parse_args(argv)
+    compile_cache.enable()
     if args.fleet and args.workload == "lm":
         parser.error("--fleet serves posterior workloads, not the lm demo")
     if args.subposterior > 1 or args.stream:
@@ -1240,6 +1249,11 @@ def main(argv=None) -> None:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count={args.devices}".strip()
             )
+        # The flag only multiplies *host* devices; on a TPU it would be
+        # ignored and the fleet would run on the chips it finds instead.
+        if jax.default_backend() == "tpu":
+            parser.error("--devices forces virtual CPU devices and has no "
+                         "effect on a TPU; drop it to use the chips present")
     if args.workload != "lm":
         # Guard legacy invocations: the pre-serving CLI was LM-only and had
         # no --workload flag, so `serve --arch ... --batch 8` must not be

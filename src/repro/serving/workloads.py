@@ -162,7 +162,9 @@ def make_ppl_workload(
 
     specs = {
         "predictive": QuerySpec(
-            fn=lambda wd, xs: jax.nn.sigmoid(xs @ wd),
+            fn=lambda wd, xs: jax.nn.sigmoid(
+                jnp.dot(xs, wd, precision=jax.lax.Precision.HIGHEST)
+            ),
             aggregate="mean",
             make_queries=make_queries,
             name="predictive",

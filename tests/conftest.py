@@ -5,25 +5,18 @@ the default run excludes it via `addopts = "-m 'not slow'"` so the tier-1
 command stays CPU-minutes cheap. Run `pytest -m slow` (or override with
 `-m ''`) for the full-size chains and subprocess multi-device cases.
 """
-import os
-
+import jax
 import numpy as np
 import pytest
 
+from repro import compile_cache
+
 # Persistent XLA compilation cache: the tier-1 suite is dominated by jit
 # compiles of the MH-in-while_loop graphs, which are identical run to run.
-# Warm runs cut compile time ~5x. Safe to enable unconditionally (the dir is
-# created lazily; unsupported backends just ignore it).
-try:
-    import jax
-
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-except Exception:  # pragma: no cover - very old jax
-    pass
+# Warm runs cut compile time ~5x. $JAX_COMPILATION_CACHE_DIR places it from
+# outside; otherwise it is <checkout>/.jax_cache.
+compile_cache.enable()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 
 
 def pytest_configure(config):

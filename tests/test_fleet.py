@@ -15,8 +15,8 @@ import sys
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings
-from _hypothesis_compat import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax
 import jax.numpy as jnp

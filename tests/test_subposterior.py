@@ -36,8 +36,8 @@ from repro.partition import (
     unflatten_draws,
 )
 
-from _hypothesis_compat import HealthCheck, given, settings
-from _hypothesis_compat import strategies as st
+from hypothesis import HealthCheck, given, settings
+from hypothesis import strategies as st
 
 # ---------------------------------------------------------------------------
 # Partitioner
