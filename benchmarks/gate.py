@@ -49,7 +49,6 @@ METRIC_DIRECTIONS = {
     "transitions_per_sec": HIGHER,
     "tps_mesh_2d": HIGHER,
     "gflops": HIGHER,
-    "achieved_frac_peak": HIGHER,
     "p50_ms": LOWER,
     "p95_ms": LOWER,
     "p99_ms": LOWER,

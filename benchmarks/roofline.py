@@ -16,9 +16,10 @@ wall time with the kernel's analytic operation/byte model:
   * ``tpu_bound``        which side of the TPU-v5e roofline the analytic
                          model puts the kernel on (compute vs memory), with
                          the corresponding ideal per-call seconds
-  * ``achieved_frac_peak``  measured FLOP rate over the roofline-limited
-                         rate ``min(PEAK_FLOPS, intensity * HBM_BW)`` — the
-                         headline "fraction of attainable peak" per kernel
+
+The wall time is the host's clock on whatever backend runs this; no share
+of a device peak is derived from it (a kernel's share of its roofline on
+the chip is read from the device trace by ``bench/``).
 
 Each bytes-bound family also runs a ``*_bf16`` variant (the
 ``precision="bf16"`` data path: gathered slabs and matmul operands in
@@ -230,8 +231,6 @@ def measure(case: dict) -> dict:
     flops, bmin = case["flops"], case["bytes_min"]
     tpu_compute_s = flops / PEAK_FLOPS
     tpu_memory_s = bmin / HBM_BW
-    # the attainable FLOP rate at this arithmetic intensity — the roofline
-    roof_flops = min(PEAK_FLOPS, (flops / bmin) * HBM_BW)
     rec = {
         "kind": "roofline",
         "name": case["name"],
@@ -247,7 +246,6 @@ def measure(case: dict) -> dict:
         "gbs": bmin / sec / 1e9,
         "tpu_bound": "compute" if tpu_compute_s >= tpu_memory_s else "memory",
         "tpu_ideal_us": max(tpu_compute_s, tpu_memory_s) * 1e6,
-        "achieved_frac_peak": (flops / sec) / roof_flops,
     }
     if "naive_bytes" in case:
         rec["traffic_ratio_naive_over_fused"] = case["naive_bytes"] / bmin

@@ -224,22 +224,27 @@ def _make_batched_transition(
             active = ~done
             pairs = jax.vmap(jax.random.split)(tk)
             tkey, sub = pairs[:, 0], pairs[:, 1]
-            if adaptive:
-                smp2, idx, valid = jax.vmap(
-                    lambda k, s, m: draw_bounded(k, s, m_max, m)
-                )(sub, smp, batch_eff)
-            else:
-                smp2, idx, valid = jax.vmap(lambda k, s: draw_fn(k, s, m_max))(sub, smp)
+            with jax.named_scope("draw"):
+                if adaptive:
+                    smp2, idx, valid = jax.vmap(
+                        lambda k, s, m: draw_bounded(k, s, m_max, m)
+                    )(sub, smp, batch_eff)
+                else:
+                    smp2, idx, valid = jax.vmap(
+                        lambda k, s: draw_fn(k, s, m_max)
+                    )(sub, smp)
             idx = _lc_round(idx)
-            if use_fused:
-                l = target.log_local_ensemble(theta, th_p, idx)
-            else:
-                l = jax.vmap(target.log_local)(theta, th_p, idx)
+            with jax.named_scope("delta"):
+                if use_fused:
+                    l = target.log_local_ensemble(theta, th_p, idx)
+                else:
+                    l = jax.vmap(target.log_local)(theta, th_p, idx)
             l = _lc_replicate_round(l)
-            w2 = jax.vmap(Welford.merge_batch)(w, l, valid)
-            dec, pv, test_ok, exhausted = jax.vmap(
-                lambda w_, m_, e: test_round_decision(w_, m_, n_total, e)
-            )(w2, mu0, epsilon)
+            with jax.named_scope("seq_test"):
+                w2 = jax.vmap(Welford.merge_batch)(w, l, valid)
+                dec, pv, test_ok, exhausted = jax.vmap(
+                    lambda w_, m_, e: test_round_decision(w_, m_, n_total, e)
+                )(w2, mu0, epsilon)
             rounds2 = rounds + 1
             fin = test_ok | exhausted | (rounds2 >= max_rounds)
             return (
@@ -936,24 +941,27 @@ class ChainEnsemble:
                 theta_prop = _lc_chains(theta_prop)
                 pairs = jax.vmap(jax.random.split)(test_key)
                 tkey, sub = pairs[:, 0], pairs[:, 1]
-                if adaptive:
-                    sampler2, idx, valid = jax.vmap(
-                        lambda k, s, m: draw_bounded(k, s, m_max, m)
-                    )(sub, sampler, batch_eff)
-                else:
-                    sampler2, idx, valid = jax.vmap(
-                        lambda k, s: draw_fn(k, s, m_max)
-                    )(sub, sampler)
+                with jax.named_scope("draw"):
+                    if adaptive:
+                        sampler2, idx, valid = jax.vmap(
+                            lambda k, s, m: draw_bounded(k, s, m_max, m)
+                        )(sub, sampler, batch_eff)
+                    else:
+                        sampler2, idx, valid = jax.vmap(
+                            lambda k, s: draw_fn(k, s, m_max)
+                        )(sub, sampler)
                 idx = _lc_round(idx)
-                if use_fused:
-                    l = target.log_local_ensemble(theta_cur, theta_prop, idx)
-                else:
-                    l = jax.vmap(target.log_local)(theta_cur, theta_prop, idx)
+                with jax.named_scope("delta"):
+                    if use_fused:
+                        l = target.log_local_ensemble(theta_cur, theta_prop, idx)
+                    else:
+                        l = jax.vmap(target.log_local)(theta_cur, theta_prop, idx)
                 l = _lc_replicate_round(l)
-                w2 = jax.vmap(Welford.merge_batch)(welford, l, valid)
-                decision, pval, test_ok, exhausted = jax.vmap(
-                    lambda w, m, e: test_round_decision(w, m, n_total, e)
-                )(w2, mu0, epsilon)
+                with jax.named_scope("seq_test"):
+                    w2 = jax.vmap(Welford.merge_batch)(welford, l, valid)
+                    decision, pval, test_ok, exhausted = jax.vmap(
+                        lambda w, m, e: test_round_decision(w, m, n_total, e)
+                    )(w2, mu0, epsilon)
                 rounds2 = rounds + 1
                 done = active & (test_ok | exhausted | (rounds2 >= max_rounds))
 

@@ -142,17 +142,24 @@ def sequential_test(
 
     def body(st: _St):
         key, sub = jax.random.split(st.key)
-        if batch_eff is None:
-            sampler, idx, valid = draw_fn(sub, st.sampler, batch_size)
-        else:
-            sampler, idx, valid = draw_bounded_fn(sub, st.sampler, batch_size, batch_eff)
-        if stateful:
-            l, new_aux = eval_fn(idx, st.aux)
-        else:
-            l, new_aux = eval_fn(idx), st.aux
-        w = st.welford.merge_batch(l, valid)
-        rounds = st.rounds + 1
-        decision, pval, test_ok, exhausted = test_round_decision(w, mu0, n_total, epsilon)
+        with jax.named_scope("draw"):
+            if batch_eff is None:
+                sampler, idx, valid = draw_fn(sub, st.sampler, batch_size)
+            else:
+                sampler, idx, valid = draw_bounded_fn(
+                    sub, st.sampler, batch_size, batch_eff
+                )
+        with jax.named_scope("delta"):
+            if stateful:
+                l, new_aux = eval_fn(idx, st.aux)
+            else:
+                l, new_aux = eval_fn(idx), st.aux
+        with jax.named_scope("seq_test"):
+            w = st.welford.merge_batch(l, valid)
+            rounds = st.rounds + 1
+            decision, pval, test_ok, exhausted = test_round_decision(
+                w, mu0, n_total, epsilon
+            )
         done = test_ok | exhausted | (rounds >= max_rounds)
         return _St(key, sampler, w, rounds, done, decision, pval, new_aux)
 

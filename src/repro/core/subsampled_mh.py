@@ -88,14 +88,15 @@ def propose_and_mu0(
     ``scale`` argument — the adaptive-proposal hook; ``None`` keeps the
     two-argument call and is bit-for-bit the pre-scale behavior.
     """
-    k_u, k_prop, k_test = jax.random.split(key, 3)
-    log_u = jnp.log(jax.random.uniform(k_u, (), jnp.float32, 1e-20, 1.0))
-    if prop_scale is None:
-        theta_p, corr = proposal(k_prop, theta)
-    else:
-        theta_p, corr = proposal(k_prop, theta, prop_scale)
-    g = target.log_global(theta, theta_p) + corr  # Detach&Regen(global)
-    mu0 = (log_u - g) / target.num_sections
+    with jax.named_scope("propose"):
+        k_u, k_prop, k_test = jax.random.split(key, 3)
+        log_u = jnp.log(jax.random.uniform(k_u, (), jnp.float32, 1e-20, 1.0))
+        if prop_scale is None:
+            theta_p, corr = proposal(k_prop, theta)
+        else:
+            theta_p, corr = proposal(k_prop, theta, prop_scale)
+        g = target.log_global(theta, theta_p) + corr  # Detach&Regen(global)
+        mu0 = (log_u - g) / target.num_sections
     return theta_p, mu0, log_u, k_test
 
 

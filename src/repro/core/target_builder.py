@@ -50,10 +50,13 @@ _LOG2PI = 1.8378770664093453
 
 def _gather(arr: jax.Array, idx: jax.Array, section_ndim: int) -> jax.Array:
     """Gather sections: shared pool (N, ...) with any idx shape, or per-chain
-    pool (K, N, ...) with (K, m) idx."""
-    if arr.ndim == section_ndim + 1:
-        return arr[idx]
-    return jax.vmap(lambda a, i: a[i])(arr, idx)
+    pool (K, N, ...) with (K, m) idx. Every family's gather (and
+    :func:`_gather_sharded`'s) runs under the named scope ``gather``, which
+    marks its device ops in the compiled program's metadata."""
+    with jax.named_scope("gather"):
+        if arr.ndim == section_ndim + 1:
+            return arr[idx]
+        return jax.vmap(lambda a, i: a[i])(arr, idx)
 
 
 def _gather_sharded(arr: jax.Array, idx: jax.Array, section_ndim: int) -> jax.Array:

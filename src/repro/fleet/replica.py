@@ -232,7 +232,8 @@ def _traced_query(replica: ReplicaEnsemble, spec: QuerySpec, xs, trace):
         raw["trace_id"] = trace_id
         if raw.get("span_id") is None:
             raw["span_id"] = new_span_id()
-        raw["parent_id"] = serve_span["span_id"]
+        if raw.get("parent_id") is None:  # children keep their parent
+            raw["parent_id"] = serve_span["span_id"]
         spans.append(raw)
     return values, snap, spans
 

@@ -105,6 +105,8 @@ class Fleet:
 
     def __init__(self, config: FleetConfig | None = None):
         self.config = config or FleetConfig()
+        # Writers live in the pool, so ``pool.tracer`` (if set) times their
+        # refresh blocks.
         self.pool = EnsemblePool(self.config.serving)
         self._workloads: dict[str, ServingWorkload] = {}
         self._shards: dict[str, list[FleetShard]] = {}
@@ -465,7 +467,7 @@ class Fleet:
                         # runtime-attached replica.
                         shard = self._shards[name][idx]
                         try:
-                            shard.writer.refresh()
+                            shard.writer.refresh(cause="background")
                             self.sync_shard(shard)
                             self._shard_errors.pop(shard.name, None)
                         except Exception as e:  # noqa: BLE001 — a dead

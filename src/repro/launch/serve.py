@@ -171,9 +171,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "<dir>/spans.jsonl and export a Chrome/Perfetto "
                          "<dir>/trace.json on exit (prints TRACE_OK)")
     ob.add_argument("--profile-dir", default=None,
-                    help="capture one jax.profiler trace of the first "
-                         "writer refresh into this directory (no-op when "
-                         "the profiler is unavailable)")
+                    help="capture one jax.profiler trace of one steady "
+                         "writer refresh block, after warm-up, into this "
+                         "directory, with the program's repro.* spans "
+                         "(no-op when the profiler is unavailable)")
     # -- legacy LM decoding flags (only read under --workload lm) ----------
     lm = ap.add_argument_group("lm decoding demo (--workload lm)")
     lm.add_argument("--arch", default="xlstm-350m", choices=list(ARCHS))
@@ -326,19 +327,34 @@ def _record_transition_cost(recorder, workload_name, snap, num_sections):
     )
 
 
-def _record_profile(recorder, args, resident) -> None:
-    """Note a completed ``--profile-dir`` capture on the ``profile`` stream
-    (no record when the one-shot capture never fired)."""
-    if recorder is None or resident is None:
+def _profile_block(recorder, args, pool, run_block) -> None:
+    """``--profile-dir``: one ``jax.profiler`` capture of one steady
+    refresh block (``run_block``), taken after warm-up, with a program
+    tracer on ``pool`` so the capture holds the ``repro.refresh.*``
+    annotations beside the device ops. Noted on the ``profile`` stream; a
+    profiler that cannot start leaves serving untouched."""
+    from repro.obs import Tracer
+
+    try:
+        jax.profiler.start_trace(args.profile_dir)
+    except Exception as e:  # noqa: BLE001 — profiling must never break serving
+        print(f"profile: jax.profiler unavailable ({type(e).__name__}: {e})")
         return
-    captured = getattr(resident, "last_profile_dir", None)
-    if captured:
-        recorder.record("profile", {
-            "workload": args.workload,
-            "capture_dir": captured,
-            "tool": "jax.profiler",
-        })
-        print(f"profile: jax.profiler capture in {captured}")
+    own_tracer = pool.tracer is None
+    if own_tracer:
+        pool.tracer = Tracer()
+    try:
+        run_block()
+    finally:
+        jax.profiler.stop_trace()
+        if own_tracer:
+            pool.tracer = None
+    recorder.record("profile", {
+        "workload": args.workload,
+        "capture_dir": args.profile_dir,
+        "tool": "jax.profiler",
+    })
+    print(f"profile: jax.profiler capture in {args.profile_dir}")
 
 
 def _stats_selfcheck(server) -> bool:
@@ -461,9 +477,6 @@ def serve_posterior(args) -> int:
           f"max_staleness={args.max_staleness_s}s")
     pool = EnsemblePool(config)
     pool.add_workload(args.workload, smoke=smoke, seed=args.seed)
-    if args.profile_dir:
-        # One-shot: the first refresh (inside warm()) lands the capture.
-        pool.resident(args.workload).arm_profile(args.profile_dir)
     workload = pool.workload(args.workload)
     print(f"target: {workload.description}; request classes: "
           f"{sorted(workload.query_specs)}")
@@ -489,13 +502,15 @@ def serve_posterior(args) -> int:
         wkey, sub = jax.random.split(wkey)
         pool.query(args.workload, cls,
                    workload.query_specs[cls].make_queries(sub, args.rows_per_query))
-    if args.background:
-        pool.start()
-
     queue = RequestQueue(pool, max_batch=args.max_batch,
                          default_deadline_s=args.deadline_ms / 1e3)
     recorder, stats_server, sampler, tracer = _setup_obs(args, source=queue)
     queue.tracer = tracer
+    pool.tracer = tracer
+    if args.profile_dir:
+        _profile_block(recorder, args, pool, resident.refresh)
+    if args.background:
+        pool.start()
     engine = _setup_alerts(args, recorder, stats_server, workload)
     num_sections = _obs_num_sections(resident.ensemble)
     classes = sorted(workload.query_specs)
@@ -576,7 +591,6 @@ def serve_posterior(args) -> int:
         snap = pool.resident(args.workload).snapshot()
         record_adaptation(recorder, args.workload, snap.summary)
         _record_transition_cost(recorder, args.workload, snap, num_sections)
-        _record_profile(recorder, args, pool.resident(args.workload))
         if engine is not None:
             engine.evaluate()
             alerts_ok = _alerts_selfcheck(engine, stats_server)
@@ -738,8 +752,6 @@ def serve_fleet(args) -> int:
             restored = fleet.restore(args.ckpt_dir)
             print(f"restored warm fleet from {args.ckpt_dir} (step {restored})")
 
-    if args.profile_dir:
-        fleet.shards(args.workload)[0].writer.arm_profile(args.profile_dir)
     t0 = time.perf_counter()
     fleet.warm()
     warm_s = time.perf_counter() - t0
@@ -752,10 +764,14 @@ def serve_fleet(args) -> int:
     router = _build_router(args, fleet, workload)
     recorder, stats_server, sampler, tracer = _setup_obs(args, source=router)
     router.tracer = tracer
+    fleet.pool.tracer = tracer
     engine = _setup_alerts(args, recorder, stats_server, workload, fleet)
     scaler = _setup_autoscaler(args, fleet, router, recorder, engine)
     num_sections = _obs_num_sections(shard0.writer.ensemble)
     _compile_lanes(args, fleet, workload, router)
+    if args.profile_dir:
+        _profile_block(recorder, args, fleet.pool,
+                       lambda: fleet.pump(args.workload))
     if args.background:
         fleet.start()
         router.start_workers()
@@ -817,7 +833,6 @@ def serve_fleet(args) -> int:
         record_snapshot(recorder, args.workload, snap)
         record_adaptation(recorder, args.workload, snap.summary)
         _record_transition_cost(recorder, args.workload, snap, num_sections)
-        _record_profile(recorder, args, shard0.writer)
         if engine is not None:
             engine.evaluate()
             alerts_ok = _alerts_selfcheck(engine, stats_server)
@@ -921,18 +936,20 @@ def serve_soak(args) -> int:
     # Killing a replica must leave a live lane in its shard.
     args.replicas = max(args.replicas, 2)
     fleet, workload, classes = _build_fleet(args)
-    if args.profile_dir:
-        fleet.shards(args.workload)[0].writer.arm_profile(args.profile_dir)
     fleet.warm()
     shard0 = fleet.shards(args.workload)[0]
     victim = shard0.replicas[-1]
     router = _build_router(args, fleet, workload)
     recorder, stats_server, sampler, tracer = _setup_obs(args, source=router)
     router.tracer = tracer
+    fleet.pool.tracer = tracer
     engine = _setup_alerts(args, recorder, stats_server, workload, fleet)
     scaler = _setup_autoscaler(args, fleet, router, recorder, engine)
     num_sections = _obs_num_sections(shard0.writer.ensemble)
     _compile_lanes(args, fleet, workload)
+    if args.profile_dir:
+        _profile_block(recorder, args, fleet.pool,
+                       lambda: fleet.pump(args.workload))
     top = workload.default_class
     print(f"soak: {soak_s:.0f}s mixed-class load "
           f"({', '.join(classes)}; top class {top!r}), "
@@ -1065,7 +1082,6 @@ def serve_soak(args) -> int:
         record_snapshot(recorder, args.workload, snap_final)
         _record_transition_cost(recorder, args.workload, snap_final,
                                 num_sections)
-        _record_profile(recorder, args, shard0.writer)
         if engine is not None:
             engine.evaluate()
             alerts_ok = _alerts_selfcheck(engine, stats_server)

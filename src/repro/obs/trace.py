@@ -22,8 +22,20 @@ field                 meaning
 ====================  =====================================================
 
 plus free-form tags. Stage tags used by the serving stack: ``request``
-(root), ``queue_wait``, ``assembly``, ``replica_serve``, ``device_eval``,
-``combine``.
+(root), ``queue_wait``, ``assembly``, ``replica_serve``, ``device_eval``
+(with children ``device_eval.upload`` and ``device_eval.run``),
+``combine``, and ``refresh``: one span per resident refresh block, its own
+trace, tagged ``cause`` (``background``, ``sync``, ``warm`` or ``call``),
+with children ``refresh.keys``, ``refresh.dispatch``, ``refresh.wait``,
+``refresh.pull`` and ``refresh.commit``.
+
+Program spans on the device trace's clock: the refresh spans and the
+evaluator's ``device_eval`` spans are opened through :func:`program_span`,
+which also opens a ``jax.profiler.TraceAnnotation`` named
+``repro.<stage>`` over the same extent. Under a ``jax.profiler`` capture
+they then sit on the host plane beside the device ops they dispatch. They
+exist only while a tracer (or a span sink) is attached: otherwise the
+call sites skip them with one ``is None`` check.
 
 Spans are plain dicts on purpose: replica worker processes build them with
 :func:`span_open`/:func:`span_close` and ship them back over the pipe
@@ -50,7 +62,13 @@ import uuid
 from collections import deque
 
 STAGES = ("request", "queue_wait", "assembly", "replica_serve",
-          "device_eval", "combine")
+          "device_eval", "device_eval.upload", "device_eval.run", "combine",
+          "refresh", "refresh.keys", "refresh.dispatch", "refresh.wait",
+          "refresh.pull", "refresh.commit")
+
+#: Prefix of the profiler annotation that mirrors a program span: it keeps
+#: the annotation apart from any a caller opens around the same work.
+ANNOTATION_PREFIX = "repro."
 
 
 def new_trace_id() -> str:
@@ -84,6 +102,50 @@ def span_close(span: dict, **tags) -> dict:
     span["dur_s"] = time.monotonic() - span["start_s"]
     span.update(tags)
     return span
+
+
+class _ProgramSpan:
+    """Context manager behind :func:`program_span`: opens the annotation
+    and stamps ``start_s`` on enter; on exit closes both (an exception
+    adds an ``error`` tag) and hands the span to ``on_close``."""
+
+    __slots__ = ("span", "_on_close", "_annotation")
+
+    def __init__(self, span: dict, on_close):
+        self.span = span
+        self._on_close = on_close
+        self._annotation = None
+
+    def __enter__(self) -> dict:
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation(ANNOTATION_PREFIX + self.span["stage"])
+        self._annotation.__enter__()
+        self.span["start_s"] = time.monotonic()
+        return self.span
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        span_close(self.span)
+        if exc_type is not None:
+            self.span["error"] = exc_type.__name__
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._on_close(self.span)
+
+
+def program_span(on_close, stage: str, name: str | None = None, *,
+                 trace_id: str | None = None, parent: dict | None = None,
+                 **tags) -> _ProgramSpan:
+    """A span over a stage of the program, mirrored by the profiler
+    annotation ``repro.<stage>``. ``on_close`` receives the closed span
+    (``Tracer.emit``, or a raw span sink's ``append``). A ``parent`` span
+    puts it in the parent's trace, under the parent."""
+    parent_id = None
+    if parent is not None:
+        trace_id, parent_id = parent["trace_id"], parent["span_id"]
+    return _ProgramSpan(
+        span_open(trace_id, name or stage, stage, parent_id=parent_id, **tags),
+        on_close,
+    )
 
 
 class Tracer:
@@ -123,6 +185,14 @@ class Tracer:
     def finish(self, span: dict, **tags) -> dict:
         """Close and emit an open span."""
         return self.emit(span_close(span, **tags))
+
+    def program_span(self, stage: str, parent: dict | None = None,
+                     **tags) -> _ProgramSpan:
+        """A :func:`program_span` emitted here on close: under ``parent``,
+        or the root of a trace of its own."""
+        return program_span(self.emit, stage,
+                            trace_id=None if parent else new_trace_id(),
+                            parent=parent, **tags)
 
     def emit(self, span: dict) -> dict:
         """Collect an already-closed span (ring + recorder + JSONL tee)."""
@@ -173,6 +243,9 @@ class Tracer:
 # Chrome/Perfetto trace_event export
 # ---------------------------------------------------------------------------
 
+#: The Chrome-trace thread track that refresh spans take under their pid.
+REFRESH_TID = 0
+
 _META_FIELDS = ("trace_id", "span_id", "parent_id", "name", "stage",
                 "start_s", "dur_s", "pid", "t", "rel_s")
 
@@ -181,21 +254,26 @@ def chrome_trace_events(spans) -> dict:
     """Closed spans -> Chrome ``trace_event`` JSON (complete "X" events,
     microsecond timestamps relative to the earliest span; one track per
     originating pid, so replica-process spans sit on their own row while
-    still nesting on the shared monotonic timeline)."""
+    still nesting on the shared monotonic timeline; refresh blocks take
+    track ``REFRESH_TID`` of their pid)."""
     closed = [s for s in spans if s.get("dur_s") is not None]
     t0 = min((s["start_s"] for s in closed), default=0.0)
     events = []
     for s in sorted(closed, key=lambda s: s["start_s"]):
         args = {k: v for k, v in s.items() if k not in _META_FIELDS}
         args["trace_id"] = s.get("trace_id")
+        stage = s.get("stage", "span")
+        pid = s.get("pid", 0)
         events.append({
             "name": s.get("name", "?"),
-            "cat": s.get("stage", "span"),
+            "cat": stage,
             "ph": "X",
             "ts": round((s["start_s"] - t0) * 1e6, 3),
             "dur": round(s["dur_s"] * 1e6, 3),
-            "pid": s.get("pid", 0),
-            "tid": s.get("pid", 0),
+            "pid": pid,
+            # refresh blocks run beside requests, not inside them: a lane
+            # of their own under the same process
+            "tid": REFRESH_TID if stage.startswith("refresh") else pid,
             "args": args,
         })
     return {"traceEvents": events, "displayTimeUnit": "ms"}

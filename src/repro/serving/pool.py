@@ -125,10 +125,24 @@ class ServingConfig:
 class EnsemblePool:
     """Named resident ensembles behind one freshness-enforcing query API."""
 
-    def __init__(self, config: ServingConfig | None = None):
+    def __init__(self, config: ServingConfig | None = None, tracer=None):
         self.config = config or ServingConfig()
         self._workloads: dict[str, ServingWorkload] = {}
         self._residents: dict[str, ResidentEnsemble] = {}
+        self._tracer = tracer
+
+    @property
+    def tracer(self):
+        """The :class:`repro.obs.trace.Tracer` every resident's refresh
+        blocks report to (None: untraced). Setting it reaches every
+        resident, present and future."""
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        self._tracer = tracer
+        for resident in self._residents.values():
+            resident.tracer = tracer
 
     # -- registration ------------------------------------------------------
 
@@ -158,6 +172,7 @@ class EnsemblePool:
             refresh_steps=cfg.refresh_steps,
             micro_batch=cfg.micro_batch,
             name=name,
+            tracer=self._tracer,
         )
         self._workloads[name] = workload
         self._residents[name] = resident
@@ -180,6 +195,9 @@ class EnsemblePool:
     def ensure_fresh(self, name: str) -> Snapshot:
         """Refresh ``name`` until its snapshot passes the freshness policy;
         returns the admitted snapshot."""
+        return self._refresh_until_fresh(name, "sync")
+
+    def _refresh_until_fresh(self, name: str, cause: str) -> Snapshot:
         resident = self._residents[name]
         policy = self.config.freshness
         snap = resident.snapshot()
@@ -190,7 +208,7 @@ class EnsemblePool:
                     f"freshness unreachable for {name!r} after {refreshes} "
                     f"refreshes: {policy.stale_reason(snap)}"
                 )
-            resident.refresh()
+            resident.refresh(cause=cause)
             refreshes += 1
             snap = resident.snapshot()
         return snap
@@ -198,7 +216,7 @@ class EnsemblePool:
     def warm(self) -> None:
         """Bring every resident to a servable snapshot (initial burn)."""
         for name in self.names():
-            self.ensure_fresh(name)
+            self._refresh_until_fresh(name, "warm")
 
     # -- streaming append --------------------------------------------------
 

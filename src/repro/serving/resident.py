@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import os
 import threading
 import time
 from typing import Any, Callable, NamedTuple
@@ -40,6 +39,7 @@ import numpy as np
 from ..checkpoint.manager import _flatten_names
 from ..core.ensemble import ChainEnsemble, EnsembleState
 from ..core.stats import ensemble_summary
+from ..obs.trace import program_span
 
 Params = Any
 
@@ -82,6 +82,17 @@ class Snapshot(NamedTuple):
     staleness_s: float  # age of the newest draw at snapshot time
     summary: dict  # ensemble_summary of the last refresh's infos
     created_at: float  # time.monotonic() at construction
+
+
+#: What a stage opens when nothing traces it: a shared no-op.
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _stage(tracer, stage: str, parent: dict | None, **tags):
+    """The tracer's program span over a refresh stage, or the no-op."""
+    if tracer is None:
+        return _NO_SPAN
+    return tracer.program_span(stage, parent, **tags)
 
 
 def _summarize_infos(infos) -> dict:
@@ -150,27 +161,33 @@ class SnapshotEvaluator:
         # Per-row results are unchanged by padding or chunking (the compiled
         # reduction shape is fixed at (S, mb), and both reductions are
         # column-independent), so the exact-equality batching contracts hold.
+        # The named scope ``query_eval`` marks the evaluator's device ops in
+        # the compiled program's metadata (and so in a profiler trace).
         cache_key = (spec.fn, spec.aggregate)
         fn = self._eval_cache.get(cache_key)
         if fn is None:
             if spec.aggregate == "mean":
-                fn = jax.jit(
-                    lambda draws, xs: jax.vmap(spec.fn, in_axes=(0, None))(
-                        draws, xs
-                    ).mean(axis=0)
-                )
+
+                def _mean(draws, xs):
+                    with jax.named_scope("query_eval"):
+                        return jax.vmap(spec.fn, in_axes=(0, None))(
+                            draws, xs
+                        ).mean(axis=0)
+
+                fn = jax.jit(_mean)
             else:  # quantile: xs[b] carries the level for row b up front
 
                 def _quantile(draws, xs):
-                    per_draw = jax.vmap(spec.fn, in_axes=(0, None))(
-                        draws, xs
-                    )  # (S, mb)
-                    levels = jnp.clip(
-                        xs.reshape(xs.shape[0], -1)[:, 0], 0.0, 1.0
-                    ).astype(per_draw.dtype)
-                    return jax.vmap(jnp.quantile, in_axes=(1, 0))(
-                        per_draw, levels
-                    )
+                    with jax.named_scope("query_eval"):
+                        per_draw = jax.vmap(spec.fn, in_axes=(0, None))(
+                            draws, xs
+                        )  # (S, mb)
+                        levels = jnp.clip(
+                            xs.reshape(xs.shape[0], -1)[:, 0], 0.0, 1.0
+                        ).astype(per_draw.dtype)
+                        return jax.vmap(jnp.quantile, in_axes=(1, 0))(
+                            per_draw, levels
+                        )
 
                 fn = jax.jit(_quantile)
             self._eval_cache[cache_key] = fn
@@ -183,50 +200,56 @@ class SnapshotEvaluator:
 
         ``span_sink``, when given, receives one raw ``device_eval`` trace
         span (a plain dict — no trace_id yet; the caller's Tracer adopts
-        it) covering the device-side work: window upload + every
-        micro-batched evaluator call. Kept dependency-free on purpose:
-        replica worker processes ship these dicts back over the pipe."""
-        t_open = time.monotonic()
+        it) covering the device-side work, and its children
+        ``device_eval.upload`` (the window's device copy, when this
+        snapshot is not cached yet) and ``device_eval.run`` (every
+        micro-batched evaluator call). Each is also a ``repro.<stage>``
+        profiler annotation (:func:`repro.obs.trace.program_span`). Kept
+        dependency-free on purpose: replica worker processes ship these
+        dicts back over the pipe."""
         xs = np.asarray(xs)
         if xs.ndim == 0:
             xs = xs[None]
         if xs.shape[0] == 0:
             return np.zeros((0,), np.float64)
+        if span_sink is None:
+            return self._evaluate(spec, snap, xs, None, None)
+        with program_span(span_sink.append, "device_eval",
+                          f"device_eval:{spec.name or spec.aggregate}",
+                          rows=int(xs.shape[0]),
+                          draws=int(snap.num_draws)) as span:
+            return self._evaluate(spec, snap, xs, span_sink, span)
+
+    def _evaluate(self, spec, snap, xs, sink, span) -> np.ndarray:
+        def stage(name):
+            if span is None:
+                return _NO_SPAN
+            return program_span(sink.append, name, parent=span)
+
         gen = (snap.steps_done, snap.num_draws)
         cached = self._flat_cache
         if cached is not None and cached[0] == gen:
             flat = cached[1]
         else:
-            flat = jax.tree.map(
-                lambda a: jnp.asarray(a.reshape((-1,) + a.shape[2:])), snap.draws
-            )  # (S, ...) with S = K * W
+            with stage("device_eval.upload"):
+                flat = jax.tree.map(
+                    lambda a: jnp.asarray(a.reshape((-1,) + a.shape[2:])),
+                    snap.draws,
+                )  # (S, ...) with S = K * W
             self._flat_cache = (gen, flat)
         evaluator = self._evaluator(spec)
         b, mb = xs.shape[0], self.micro_batch
         vals = []
-        for start in range(0, b, mb):
-            chunk = xs[start:start + mb]
-            pad = mb - chunk.shape[0]
-            if pad:
-                chunk = np.concatenate([chunk, np.repeat(chunk[-1:], pad, axis=0)])
-            v = np.asarray(evaluator(flat, jnp.asarray(chunk)))  # (mb,)
-            keep = slice(None, mb - pad) if pad else slice(None)
-            vals.append(v[keep])
-        out = np.concatenate(vals, axis=0).astype(np.float64)
-        if span_sink is not None:
-            span_sink.append({
-                "trace_id": None,
-                "span_id": None,
-                "parent_id": None,
-                "name": f"device_eval:{spec.name or spec.aggregate}",
-                "stage": "device_eval",
-                "start_s": t_open,
-                "dur_s": time.monotonic() - t_open,
-                "pid": os.getpid(),
-                "rows": int(b),
-                "draws": int(snap.num_draws),
-            })
-        return out
+        with stage("device_eval.run"):
+            for start in range(0, b, mb):
+                chunk = xs[start:start + mb]
+                pad = mb - chunk.shape[0]
+                if pad:
+                    chunk = np.concatenate([chunk, np.repeat(chunk[-1:], pad, axis=0)])
+                v = np.asarray(evaluator(flat, jnp.asarray(chunk)))  # (mb,)
+                keep = slice(None, mb - pad) if pad else slice(None)
+                vals.append(v[keep])
+        return np.concatenate(vals, axis=0).astype(np.float64)
 
 
 class ResidentEnsemble:
@@ -248,6 +271,7 @@ class ResidentEnsemble:
         micro_batch: int = 64,
         name: str = "resident",
         batched_theta0: bool = False,
+        tracer=None,
     ):
         if window < 1 or refresh_steps < 1 or micro_batch < 1:
             raise ValueError("window, refresh_steps, micro_batch must be >= 1")
@@ -270,11 +294,9 @@ class ResidentEnsemble:
         self._evaluator = SnapshotEvaluator(micro_batch)
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
-        # One-shot jax.profiler capture: arm_profile() points the NEXT
-        # refresh at a directory; last_profile_dir records where the
-        # capture landed (None until one has happened).
-        self._profile_dir: str | None = None
-        self.last_profile_dir: str | None = None
+        # An obs.trace.Tracer, or None: when set, every refresh block emits
+        # a "refresh" span with its stages as children (see refresh()).
+        self.tracer = tracer
 
     # -- refresh -----------------------------------------------------------
 
@@ -286,67 +308,44 @@ class ResidentEnsemble:
     def state(self) -> EnsembleState:
         return self._state
 
-    def arm_profile(self, profile_dir: str) -> None:
-        """Capture a ``jax.profiler`` trace of the *next* refresh block
-        into ``profile_dir`` (one-shot; re-arm for another capture). The
-        capture is best-effort: an unavailable or failing profiler leaves
-        refresh untouched — what ``serve --profile-dir`` relies on."""
-        self._profile_dir = profile_dir
-
-    def _profile_ctx(self):
-        """A context manager wrapping one refresh run: the armed one-shot
-        ``jax.profiler.trace`` capture, or a no-op. Never raises."""
-        profile_dir, self._profile_dir = self._profile_dir, None
-        if profile_dir is None:
-            return contextlib.nullcontext(), None
-        try:
-            from jax import profiler as jax_profiler
-
-            return jax_profiler.trace(profile_dir), profile_dir
-        except Exception:  # noqa: BLE001 — profiling must never break serving
-            return contextlib.nullcontext(), None
-
-    def refresh(self, num_steps: int | None = None) -> int:
+    def refresh(self, num_steps: int | None = None, *, cause: str = "call") -> int:
         """Advance every chain ``num_steps`` (default ``refresh_steps``)
         transitions and fold the collected draws into the window.
 
         Runs on the resumable step-key schedule, so any sequence of refresh
         calls equals one offline ``ensemble.run`` over the same total steps
         (same base key) bit for bit.
+
+        With a tracer attached the block is a ``refresh`` span tagged
+        ``cause`` (``background``, ``sync``, ``warm`` or ``call``) whose
+        children time its host stages: ``refresh.keys`` (step keys),
+        ``refresh.dispatch`` (the run's dispatch), ``refresh.wait`` (until
+        the device is done), ``refresh.pull`` (draws and infos to the host)
+        and ``refresh.commit``.
         """
         n = self.refresh_steps if num_steps is None else int(num_steps)
         if n < 1:
             raise ValueError(f"refresh needs num_steps >= 1, got {n}")
-        with self._refresh_lock:
+        tracer = self.tracer
+        with self._refresh_lock, _stage(tracer, "refresh", None, cause=cause,
+                                        workload=self.name, steps=n) as block:
             # Only mutators hold _refresh_lock, so these reads are stable;
             # the expensive run happens with _lock released and snapshots
             # keep serving the previous window meanwhile.
             with self._lock:
                 state, steps_done = self._state, self._steps_done
-            sk = self.ensemble.step_keys(self._base_key, steps_done, n)
-            ctx, profiled = self._profile_ctx()
-            try:
-                with ctx:
-                    state, samples, infos = self.ensemble.run(
-                        None, state, n, step_keys=sk
-                    )
-                    jax.block_until_ready(state.theta)
-            except Exception:
-                if profiled is None:
-                    raise
-                # The profiler context itself failed (e.g. a second trace
-                # already active): redo the block unprofiled — the capture
-                # is best-effort, the refresh is not.
-                profiled = None
+            with _stage(tracer, "refresh.keys", block):
+                sk = self.ensemble.step_keys(self._base_key, steps_done, n)
+            with _stage(tracer, "refresh.dispatch", block):
                 state, samples, infos = self.ensemble.run(
                     None, state, n, step_keys=sk
                 )
+            with _stage(tracer, "refresh.wait", block):
                 jax.block_until_ready(state.theta)
-            if profiled is not None:
-                self.last_profile_dir = profiled
-            draws = _window_append(self._draws, samples, self.window)
-            last_infos = jax.tree.map(np.asarray, infos)
-            with self._lock:
+            with _stage(tracer, "refresh.pull", block):
+                draws = _window_append(self._draws, samples, self.window)
+                last_infos = jax.tree.map(np.asarray, infos)
+            with _stage(tracer, "refresh.commit", block), self._lock:
                 self._draws = draws
                 self._last_infos = last_infos
                 self._state = state
@@ -463,7 +462,7 @@ class ResidentEnsemble:
 
             def loop():
                 while not self._stop.is_set():
-                    self.refresh()
+                    self.refresh(cause="background")
                     if interval_s:
                         self._stop.wait(interval_s)
 

@@ -1,12 +1,15 @@
 """End-to-end request tracing: span lifecycle, the Tracer ring/stream,
-Chrome/Perfetto export, queue-path propagation, and (slow tier) the
-cross-process ReplicaProcess round-trip — one trace_id spanning two OS
-processes on the shared monotonic timeline.
+Chrome/Perfetto export, queue-path propagation, the program's refresh and
+evaluator spans with their profiler annotations, the named scopes of the
+compiled programs, and (slow tier) the cross-process ReplicaProcess
+round-trip — one trace_id spanning two OS processes on the shared
+monotonic timeline.
 """
 import json
 import os
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -15,6 +18,7 @@ import jax
 
 from repro.obs import Recorder, Tracer, chrome_trace_events
 from repro.obs.trace import (
+    REFRESH_TID,
     STAGES,
     load_spans,
     main as trace_main,
@@ -293,3 +297,282 @@ if __name__ == "__main__":
     assert res["parent_pid"] in res["pids"]
     assert res["nested"] is True  # monotone clock shared across processes
     assert res["events_ok"] is True and res["n_events"] >= 3
+
+
+# ---------------------------------------------------------------------------
+# Program spans: refresh blocks and the evaluator's stages
+# ---------------------------------------------------------------------------
+
+REFRESH_STAGES = ("refresh.keys", "refresh.dispatch", "refresh.wait",
+                  "refresh.pull", "refresh.commit")
+
+
+def _small_pool(tracer=None, **policy):
+    cfg = ServingConfig(
+        num_chains=2, refresh_steps=8, window=16, micro_batch=8, max_batch=4,
+        freshness=FreshnessPolicy(max_staleness_s=60.0, min_draws=32), seed=0,
+    )
+    pool = EnsemblePool(cfg, tracer=tracer)
+    pool.add_workload("bayeslr", smoke=True, n_train=400, d=3, batch_size=50)
+    return pool
+
+
+def _blocks(spans, cause=None):
+    return [s for s in spans if s["stage"] == "refresh"
+            and (cause is None or s["cause"] == cause)]
+
+
+def _check_block(spans, block):
+    kids = sorted((s for s in spans if s.get("parent_id") == block["span_id"]),
+                  key=lambda s: s["start_s"])
+    assert [s["stage"] for s in kids] == list(REFRESH_STAGES)
+    for kid in kids:
+        assert kid["trace_id"] == block["trace_id"]
+        assert _contains(block, kid)
+    for a, b in zip(kids, kids[1:]):  # stages run one after the other
+        assert a["start_s"] + a["dur_s"] <= b["start_s"] + 1e-6
+
+
+def test_traced_refresh_emits_one_block_with_its_five_stages(traced_pool):
+    tracer = Tracer()
+    traced_pool.tracer = tracer
+    try:
+        traced_pool.resident("bayeslr").refresh()
+    finally:
+        traced_pool.tracer = None
+    spans = tracer.spans()
+    (block,) = _blocks(spans)
+    assert block["cause"] == "call" and block["parent_id"] is None
+    assert block["workload"] == "bayeslr" and block["steps"] == 8
+    assert len(spans) == 1 + len(REFRESH_STAGES)
+    _check_block(spans, block)
+
+
+def test_refresh_cause_tags_sync_warm_and_background():
+    tracer = Tracer()
+    synced = _small_pool(tracer)
+    synced.ensure_fresh("bayeslr")  # two blocks reach min_draws=32
+    assert [b["cause"] for b in _blocks(tracer.spans())] == ["sync", "sync"]
+
+    tracer = Tracer()
+    warmed = _small_pool()
+    warmed.tracer = tracer  # attached after the residents exist
+    warmed.warm()
+    blocks = _blocks(tracer.spans())
+    assert [b["cause"] for b in blocks] == ["warm", "warm"]
+    for block in blocks:
+        _check_block(tracer.spans(), block)
+
+    warmed.start()
+    try:
+        deadline = time.monotonic() + 60.0
+        while not _blocks(tracer.spans(), "background"):
+            assert time.monotonic() < deadline, "no background block"
+            time.sleep(0.01)
+    finally:
+        warmed.stop()
+    for block in _blocks(tracer.spans(), "background"):
+        _check_block(tracer.spans(), block)
+
+
+def test_untraced_refresh_builds_no_span_and_opens_no_annotation(
+        traced_pool, monkeypatch):
+    import repro.obs.trace as trace_mod
+
+    opened = []
+
+    class Counting:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    real_open = trace_mod.span_open
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    monkeypatch.setattr(trace_mod, "span_open",
+                        lambda *a, **kw: opened.append(a) or real_open(*a, **kw))
+    resident = traced_pool.resident("bayeslr")
+    assert resident.tracer is None
+    resident.refresh()
+    assert opened == []
+    # the same refresh, traced, opens the block and its five stages
+    resident.tracer = Tracer()
+    try:
+        resident.refresh()
+    finally:
+        resident.tracer = None
+    names = [o for o in opened if isinstance(o, str)]
+    assert names == ["repro.refresh", *("repro." + s for s in REFRESH_STAGES)]
+
+
+def test_evaluator_splits_device_eval_into_upload_and_run(traced_pool):
+    from repro.serving.resident import SnapshotEvaluator
+
+    resident = traced_pool.resident("bayeslr")
+    spec = traced_pool.spec("bayeslr", "predictive")
+    snap = resident.snapshot()
+    xs = spec.make_queries(jax.random.key(0), 3)
+    evaluator = SnapshotEvaluator(micro_batch=8)
+    sink = []
+    plain = evaluator.evaluate(spec, snap, xs)
+    evaluator.invalidate()
+    traced = evaluator.evaluate(spec, snap, xs, span_sink=sink)
+    np.testing.assert_array_equal(plain, traced)
+    (top,) = [s for s in sink if s["stage"] == "device_eval"]
+    assert top["name"] == "device_eval:predictive" and top["rows"] == 3
+    kids = {s["stage"]: s for s in sink if s is not top}
+    assert set(kids) == {"device_eval.upload", "device_eval.run"}
+    for kid in kids.values():
+        assert kid["parent_id"] == top["span_id"] and _contains(top, kid)
+    # the window is cached now: a second evaluation uploads nothing
+    sink.clear()
+    evaluator.evaluate(spec, snap, xs, span_sink=sink)
+    assert sorted(s["stage"] for s in sink) == ["device_eval", "device_eval.run"]
+
+
+def test_queue_adopts_the_evaluator_stages_under_device_eval(traced_pool):
+    tracer = Tracer()
+    queue = RequestQueue(traced_pool, max_batch=4, tracer=tracer)
+    spec = traced_pool.workload("bayeslr").query_specs["predictive"]
+    req = queue.submit("bayeslr", "predictive",
+                       spec.make_queries(jax.random.key(5), 2))
+    queue.drain()
+    spans = tracer.trace(req.trace_id)
+    (top,) = [s for s in spans if s["stage"] == "device_eval"]
+    run = [s for s in spans if s["stage"] == "device_eval.run"]
+    assert len(run) == 1 and run[0]["parent_id"] == top["span_id"]
+    tracer.close()
+
+
+# ---------------------------------------------------------------------------
+# Named scopes in the compiled programs
+# ---------------------------------------------------------------------------
+
+
+def _scopes(lowered) -> set:
+    """Named scopes in a lowered program's op names: every component of an
+    op name but the last (the primitive), with ``vmap(...)``-style wrappers
+    opened up."""
+    import re
+
+    text = lowered.as_text(debug_info=True)
+    out = set()
+    for op_name in re.findall(r'loc\("([^"]*/[^"]*)"', text):
+        for part in op_name.split("/")[:-1]:
+            out.update(p for p in re.split(r"[()]", part) if p)
+    return out
+
+
+@pytest.mark.parametrize("stepping,fused", [
+    ("lockstep", "always"),  # the fused lock-step scan (the chip's route)
+    ("lockstep", "never"),   # the vmapped scan of single-chain steps
+    ("masked", "always"),    # the masked superstep
+])
+def test_refresh_program_carries_the_round_scopes(stepping, fused):
+    import dataclasses
+
+    from repro.serving.workloads import build_serving_workload
+
+    wl = build_serving_workload("bayeslr", smoke=True, n_train=400, d=3,
+                                batch_size=50, num_chains=2, seed=0)
+    ens = dataclasses.replace(wl.ensemble, stepping=stepping,
+                              fused_kernels=fused)
+    state = ens.init(wl.theta0)
+    sk = ens.step_keys(jax.random.key(0), 0, 4)
+    scopes = _scopes(ens.lower(state, 4, step_keys=sk))
+    assert {"draw", "gather", "delta", "seq_test", "propose"} <= scopes
+
+
+def test_evaluator_program_carries_query_eval(traced_pool):
+    resident = traced_pool.resident("bayeslr")
+    spec = traced_pool.spec("bayeslr", "predictive")
+    snap = resident.snapshot()
+    flat = snap.draws.reshape((-1,) + snap.draws.shape[2:])
+    xs = spec.make_queries(jax.random.key(1), 8)
+    lowered = resident._evaluator._evaluator(spec).lower(flat, xs)
+    assert "query_eval" in _scopes(lowered)
+
+
+# ---------------------------------------------------------------------------
+# The same spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_refresh_annotations_nest_on_the_profiler_host_plane(traced_pool,
+                                                             tmp_path):
+    from jax.profiler import ProfileData
+
+    resident = traced_pool.resident("bayeslr")
+    tracer = Tracer()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    resident.tracer = tracer
+    try:
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            resident.refresh()
+    finally:
+        resident.tracer = None
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for p in ProfileData.from_file(path).planes
+              if not p.name.startswith("/device:")
+              for line in p.lines for e in line.events
+              if e.name.startswith("repro.")]
+    (outer,) = [e for e in events if e[0] == "repro.refresh"]
+    inner = sorted((e for e in events if e is not outer), key=lambda e: e[1])
+    # the annotations nest as the dict spans do: the block, then its
+    # stages in order, each inside it
+    spans = tracer.spans()
+    (block,) = _blocks(spans)
+    kids = sorted((s for s in spans if s["parent_id"] == block["span_id"]),
+                  key=lambda s: s["start_s"])
+    assert [e[0] for e in inner] == ["repro." + s["stage"] for s in kids]
+    for _, a, b in inner:
+        assert outer[1] <= a <= b <= outer[2]
+
+
+def test_chrome_export_puts_refresh_blocks_on_their_own_track(traced_pool):
+    tracer = Tracer()
+    tracer.finish(tracer.new_trace("request:w.q"))
+    resident = traced_pool.resident("bayeslr")
+    resident.tracer = tracer
+    try:
+        resident.refresh()
+    finally:
+        resident.tracer = None
+    events = chrome_trace_events(tracer.spans())["traceEvents"]
+    tids = {e["cat"]: e["tid"] for e in events}
+    assert tids["request"] == os.getpid()
+    assert {tids["refresh"], tids["refresh.pull"]} == {REFRESH_TID}
+
+
+def test_serve_profile_dir_captures_one_steady_traced_block(tmp_path, capsys):
+    from jax.profiler import ProfileData
+
+    from repro.launch import serve
+
+    prof, spans_dir = tmp_path / "prof", tmp_path / "trace"
+    serve.main(["--workload", "bayeslr", "--smoke",
+                "--profile-dir", str(prof), "--trace-dir", str(spans_dir)])
+    out = capsys.readouterr().out
+    assert "SERVE_OK" in out
+    assert f"profile: jax.profiler capture in {prof}" in out
+    # the capture holds one refresh block, taken after warm-up, with the
+    # program's annotations on the host plane
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(prof)
+               for f in fs if f.endswith(".xplane.pb")]
+    names = [e.name for p in ProfileData.from_file(path).planes
+             if not p.name.startswith("/device:")
+             for line in p.lines for e in line.events
+             if e.name.startswith("repro.refresh")]
+    assert names.count("repro.refresh") == 1
+    assert {"repro." + s for s in REFRESH_STAGES} <= set(names)
+    # --trace-dir's tracer reached the pool: the export holds the blocks
+    blocks = _blocks(load_spans(str(spans_dir)))
+    assert blocks and {b["cause"] for b in blocks} <= {"call", "sync"}
