@@ -78,6 +78,7 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..distributed.sharding import lc, logical_axis_rules
 from .composite import CycleOp, SubsampledMHOp, SweepOp, init_cycle_samplers
@@ -93,7 +94,7 @@ from .subsampled_mh import (
     propose_and_mu0,
 )
 from .samplers import make_bounded_draw, make_sampler
-from .target import PartitionedTarget
+from .target import PartitionedTarget, Window
 
 Params = Any
 
@@ -162,6 +163,61 @@ def _lc_replicate_round(l: jax.Array) -> jax.Array:
     return lc(l, ("ensemble_chains", None))
 
 
+def _takes_window(target: PartitionedTarget, sampler: str, m_max: int,
+                  use_fused: bool) -> bool:
+    """Does a round read its sections as a :class:`~repro.core.target.Window`?
+    Only the ``stream`` sampler draws contiguous sections, and only the
+    builder's family closures read a window: ``log_local_ensemble`` always,
+    ``log_local`` where ``target.local_window`` says so."""
+    return (sampler == "stream" and m_max <= target.num_sections
+            and (use_fused or target.local_window))
+
+
+def _eval_round(evaluate, idx: jax.Array, pos: jax.Array, active: jax.Array,
+                m_max: int, n_total: int, window: bool) -> jax.Array:
+    """One round's (K, m) deltas, ``evaluate`` applied to the drawn (K, m)
+    index block or, when ``window``, to each chain's run [pos, pos + m_max)
+    (``pos`` the stream sampler's pre-draw position). Decided once per round,
+    outside any vmap (a vmapped cond runs both branches): the window when
+    every active chain's lies inside the pool, where the draw's clamp is a
+    no-op and the run holds exactly the drawn rows; else the index gather,
+    in the round where a chain reaches the pool's end. Inactive lanes'
+    deltas are discarded, so their runs may be clamped."""
+    if not window:
+        return evaluate(idx)
+    fits = jnp.all((pos + m_max <= n_total) | ~active)
+    start = lc(pos, ("ensemble_chains",))
+    return jax.lax.cond(fits, lambda: evaluate(Window(start, m_max)),
+                        lambda: evaluate(idx))
+
+
+def _lockstep_gather_rounds(rounds, batch_eff, m_max: int, n_total: int):
+    """``(gather, total)`` round-loop iterations of a lock-step block from its
+    (K, T) infos: step t's loop runs its slowest chain's rounds, and
+    iteration r took :func:`_eval_round`'s gather branch when a chain still
+    active there (``rounds > r``) had a run past the pool's end (the stream
+    position after r rounds is ``min(r * batch_eff, N)``)."""
+    rounds, b = np.asarray(rounds), np.asarray(batch_eff)
+    r = np.arange(int(rounds.max(initial=0)))[:, None, None]
+    crosses = (r < rounds) & (r * b + m_max > n_total)
+    return int(crosses.any(axis=1).sum()), int(rounds.max(axis=0, initial=0).sum())
+
+
+def _masked_gather_rounds(rounds, batch_eff, m_max: int, n_total: int):
+    """The same for a masked superstep block: superstep s is round s of every
+    chain whose transitions are not all committed, each chain's transitions
+    back to back, so a chain's within-transition round at s follows from the
+    prefix sums of its ``rounds``."""
+    rounds, b = np.asarray(rounds), np.asarray(batch_eff)
+    totals = rounds.sum(axis=1)
+    hit = np.zeros(int(totals.max(initial=0)), bool)
+    for rk, bk, total in zip(rounds, b, totals):
+        t = np.repeat(np.arange(rk.size), rk)  # transition of each round
+        r = np.arange(total) - (np.cumsum(rk) - rk)[t]
+        hit[:total] |= r * bk[t] + m_max > n_total
+    return int(hit.sum()), hit.size
+
+
 def _make_batched_transition(
     target: PartitionedTarget,
     proposal,
@@ -196,6 +252,8 @@ def _make_batched_transition(
     m_max = batch_max if batch_max is not None else config.batch_size
     n_total = target.num_sections
     K = num_chains
+    evaluate = target.log_local_ensemble if use_fused else jax.vmap(target.log_local)
+    window = _takes_window(target, config.sampler, m_max, use_fused)
 
     def transition(keys, theta, sampler, epsilon, batch_eff, prop_scale=None):
         if prop_scale is None:
@@ -235,10 +293,8 @@ def _make_batched_transition(
                     )(sub, smp)
             idx = _lc_round(idx)
             with jax.named_scope("delta"):
-                if use_fused:
-                    l = target.log_local_ensemble(theta, th_p, idx)
-                else:
-                    l = jax.vmap(target.log_local)(theta, th_p, idx)
+                l = _eval_round(functools.partial(evaluate, theta, th_p), idx,
+                                smp.pos, active, m_max, n_total, window)
             l = _lc_replicate_round(l)
             with jax.named_scope("seq_test"):
                 w2 = jax.vmap(Welford.merge_batch)(w, l, valid)
@@ -538,8 +594,6 @@ class ChainEnsemble:
                 f"{self.chain_axis!r} mesh axis size ({c})"
             )
         from jax.sharding import Mesh
-
-        import numpy as np
 
         return Mesh(np.asarray(devices).reshape(c, d),
                     (self.chain_axis, self.data_axis))
@@ -843,6 +897,8 @@ class ChainEnsemble:
         adaptive = sched is not None
         adapt_prop = adaptive and sched.adapt_proposal
         use_fused = self._use_fused()
+        evaluate = target.log_local_ensemble if use_fused else jax.vmap(target.log_local)
+        window = _takes_window(target, config.sampler, m_max, use_fused)
         K = self.num_chains
 
         def knobs(ctrl):
@@ -952,10 +1008,10 @@ class ChainEnsemble:
                         )(sub, sampler)
                 idx = _lc_round(idx)
                 with jax.named_scope("delta"):
-                    if use_fused:
-                        l = target.log_local_ensemble(theta_cur, theta_prop, idx)
-                    else:
-                        l = jax.vmap(target.log_local)(theta_cur, theta_prop, idx)
+                    l = _eval_round(
+                        functools.partial(evaluate, theta_cur, theta_prop), idx,
+                        sampler.pos, active, m_max, n_total, window,
+                    )
                 l = _lc_replicate_round(l)
                 with jax.named_scope("seq_test"):
                     w2 = jax.vmap(Welford.merge_batch)(welford, l, valid)
@@ -1034,8 +1090,6 @@ class ChainEnsemble:
             return None
         from jax.sharding import Mesh
 
-        import numpy as np
-
         return Mesh(np.asarray(devices), (self.chain_axis,))
 
     # -- drivers ----------------------------------------------------------
@@ -1089,14 +1143,55 @@ class ChainEnsemble:
             # bit-for-bit the vmapped scan when unfused.
             return (self._run_lockstep_fused_jit if self._use_fused()
                     else self._run_lockstep_batched_jit)
-        if (self.kernel == "subsampled" and self._use_fused()
-                and (self.fused_kernels == "always" or self._chain_mesh() is None)):
-            # The fused lock-step scan runs unsharded. An explicit "always"
-            # wins over the chain mesh (shard=True + "always" is rejected at
-            # construction); under "auto" with a mesh present, the vmapped
-            # scan keeps the multi-device fan-out instead.
+        if self._lockstep_fused():
             return self._run_lockstep_fused_jit
         return self._run_jit
+
+    def _lockstep_fused(self) -> bool:
+        # The fused lock-step scan runs unsharded. An explicit "always" wins
+        # over the chain mesh (shard=True + "always" is rejected at
+        # construction); under "auto" with a mesh present, the vmapped scan
+        # keeps the multi-device fan-out instead.
+        return (self.kernel == "subsampled" and self._use_fused()
+                and (self.fused_kernels == "always" or self._chain_mesh() is None))
+
+    @functools.cached_property
+    def _window_loops(self) -> tuple:
+        """``(info_name, m_max, n_total, masked)`` of each round loop of
+        :meth:`run` whose rounds read windows (:func:`_takes_window`);
+        ``info_name`` is a composite component's, else None."""
+        if self.transition is not None:
+            names = self.transition.names
+            return tuple(
+                (names[i], op.cfg.batch_size, op.target.num_sections, False)
+                for i, op in self.transition.mh_ops
+                if _takes_window(op.target, op.cfg.sampler, op.cfg.batch_size,
+                                 self._fused_for(op.target))
+            )
+        batched = (self.stepping == "masked" or self._shard_2d_request is not None
+                   or self._lockstep_fused())
+        m_max = max(self._buckets)
+        if batched and _takes_window(self.target, self._config.sampler, m_max,
+                                     self._use_fused()):
+            return ((None, m_max, self.target.num_sections,
+                     self.stepping == "masked"),)
+        return ()
+
+    def section_tags(self, infos) -> dict:
+        """How a block's rounds read their sections, from its infos:
+        ``sections`` is ``"window"`` when :meth:`run`'s rounds read each
+        chain's stream run as one slice, else ``"gather"``; for windows,
+        ``gather_rounds`` of all ``rounds`` (round-loop iterations) took the
+        index gather instead, because a chain's run passed the pool's end."""
+        if not self._window_loops:
+            return {"sections": "gather"}
+        gather = total = 0
+        for name, m_max, n_total, masked in self._window_loops:
+            info = infos if name is None else infos[name]
+            count = _masked_gather_rounds if masked else _lockstep_gather_rounds
+            g, t = count(info.rounds, info.batch_eff, m_max, n_total)
+            gather, total = gather + g, total + t
+        return {"sections": "window", "gather_rounds": gather, "rounds": total}
 
     def run(self, key: jax.Array | None, state: EnsembleState, num_steps: int,
             *, step_keys: jax.Array | None = None):
@@ -1179,8 +1274,6 @@ class ChainEnsemble:
             ((2, 4), True, 4)
         """
         import time
-
-        import numpy as np
 
         # All step keys for this window, computed (and warmed) up front so
         # neither key generation nor a ragged final block compiles inside

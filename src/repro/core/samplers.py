@@ -114,8 +114,13 @@ class StreamSliceState(NamedTuple):
 
     The pool (e.g. the resident global batch of sequences) is assumed already
     randomly ordered by the data pipeline; a round consumes the next
-    contiguous slice. Equivalent in distribution to Fisher–Yates draws while
-    keeping every gather local to its shard.
+    contiguous run [pos, pos + m). Equivalent in distribution to Fisher–Yates
+    draws while keeping every gather local to its shard. The draw returns
+    the run as an index block, clamped at the pool's end; the ensemble's
+    batched rounds read each chain's run as one
+    :class:`~repro.core.target.Window` instead (one slice per chain, not m
+    indices) whenever every run lies inside the pool, and the index block
+    only in the round where a chain's run reaches the end.
     """
 
     pos: jax.Array  # int32 scalar

@@ -11,9 +11,10 @@ Defs. 6–8). The MH kernels in this package consume only this interface:
       handled by the Proposal object, not here.
 
   log_local(theta, theta_prime, idx) -> (m,)
-      l_i for the requested local sections: the per-section log-weight
-      products sum_{n in local_i} log w_n. For symmetric proposals over a
-      Bayesian-network-shaped scaffold this is
+      l_i for the requested local sections (an int index array, or a
+      :class:`Window` where ``local_window`` says it reads one): the
+      per-section log-weight products sum_{n in local_i} log w_n. For
+      symmetric proposals over a Bayesian-network-shaped scaffold this is
       log p(x_{local_i} | theta') - log p(x_{local_i} | theta).
 
   num_sections
@@ -32,6 +33,21 @@ Params = Any
 
 
 @dataclasses.dataclass(frozen=True)
+class Window:
+    """A round's sections as one contiguous run ``[start, start + size)`` of
+    the pool: the ``stream`` sampler's round when the whole run lies inside
+    the pool. ``start`` is a scalar, or (K,) with one run per chain; ``size``
+    is static. A family reads it as one slice per chain, where an index
+    array costs one gathered element per section."""
+
+    start: jax.Array
+    size: int
+
+
+jax.tree_util.register_dataclass(Window, data_fields=["start"], meta_fields=["size"])
+
+
+@dataclasses.dataclass(frozen=True)
 class PartitionedTarget:
     num_sections: int
     log_global: Callable[[Params, Params], jax.Array]
@@ -40,7 +56,8 @@ class PartitionedTarget:
     # by diagnostics and by gradient-informed proposals. May be None.
     log_density: Callable[[Params], jax.Array] | None = None
     # Optional ensemble-fused local evaluation: (theta, theta', idx) with a
-    # leading (K,) chain axis on every argument -> (K, m) deltas, backed by a
+    # leading (K,) chain axis on every argument (idx a (K, m) index array or a
+    # Window with (K,) starts) -> (K, m) deltas, backed by a
     # fused kernel (e.g. repro.kernels.ops.batched_logit_delta). When set and
     # the ops dispatch selects Pallas, ChainEnsemble routes each sequential-
     # test round through it instead of vmapping ``log_local``.
@@ -48,6 +65,9 @@ class PartitionedTarget:
     # Name of the registered kernel family (repro.core.target_builder) that
     # built log_local / log_local_ensemble, or None for hand-wired targets.
     family: str | None = None
+    # True when ``log_local`` also takes a Window in place of its index
+    # array (the builder's family closures); hand-wired ones take indices.
+    local_window: bool = False
     # Optional construction recipe (repro.core.target_builder.TargetSpec):
     # the family name, the section-pool data arrays, and the (possibly
     # tempered) prior the builder assembled this target from. Carrying the

@@ -25,6 +25,10 @@ Registered families:
   ``ce``            softmax cross-entropy token factors (the LM likelihood),
                     data = (h (N, D), targets (N,)), params = table (V, D)
 
+Sections are an index array, or a :class:`~repro.core.target.Window` of
+contiguous sections (the ``stream`` sampler's round), which every family
+reads as one slice per chain.
+
 ``data`` may also be a callable ``theta -> data`` for sections that are
 functions of latent state (stochvol's transition factors depend on the
 current particle-Gibbs paths ``theta["h"]``); it must only read leaves the
@@ -35,31 +39,68 @@ data pools may be shared ``(N, ...)`` or per-chain ``(K, N, ...)``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..distributed.sharding import lc, shard_local
-from .target import PartitionedTarget
+from .target import PartitionedTarget, Window
 
 Params = Any
 
 _LOG2PI = 1.8378770664093453
 
 
-def _gather(arr: jax.Array, idx: jax.Array, section_ndim: int) -> jax.Array:
+@functools.lru_cache(maxsize=None)
+def _window_reader(size: int):
+    """``read(pool, start) -> pool[start:start + size]``, whose batching rule
+    reads one slice per batch entry, unrolled: a batched ``dynamic_slice``
+    becomes a gather, which XLA's TPU backend runs as a loop of small ops
+    per round. The pool is held row-major (sections major): left free, the
+    TPU compiler lays a (N, D) pool out transposed for the slices, and every
+    round then transposes the block it read back for the kernel."""
+
+    @jax.custom_batching.custom_vmap
+    def read(pool, start):
+        pool = with_layout_constraint(pool, Layout(tuple(range(pool.ndim))))
+        return jax.lax.dynamic_slice_in_dim(pool, start, size)
+
+    @read.def_vmap
+    def _read_batched(axis_size, in_batched, pool, start):
+        pool_b, start_b = in_batched
+        return jnp.stack([
+            read(pool[k] if pool_b else pool, start[k] if start_b else start)
+            for k in range(axis_size)
+        ]), True
+
+    return read
+
+
+def _gather(arr: jax.Array, idx, section_ndim: int) -> jax.Array:
     """Gather sections: shared pool (N, ...) with any idx shape, or per-chain
-    pool (K, N, ...) with (K, m) idx. Every family's gather (and
+    pool (K, N, ...) with (K, m) idx. ``idx`` may instead be a
+    :class:`~repro.core.target.Window`, read as one slice per chain (its
+    start must keep the run inside the pool: XLA clamps a slice's start, so a
+    run past the end would shift its rows). Every family's gather (and
     :func:`_gather_sharded`'s) runs under the named scope ``gather``, which
     marks its device ops in the compiled program's metadata."""
     with jax.named_scope("gather"):
+        if isinstance(idx, Window):
+            read = _window_reader(idx.size)
+            if arr.ndim > section_ndim + 1:
+                return jax.vmap(read)(arr, idx.start)
+            if idx.start.ndim:
+                return jax.vmap(read, in_axes=(None, 0))(arr, idx.start)
+            return read(arr, idx.start)
         if arr.ndim == section_ndim + 1:
             return arr[idx]
         return jax.vmap(lambda a, i: a[i])(arr, idx)
 
 
-def _gather_sharded(arr: jax.Array, idx: jax.Array, section_ndim: int) -> jax.Array:
+def _gather_sharded(arr: jax.Array, idx, section_ndim: int) -> jax.Array:
     """Ensemble-round gather with the chains x data sharding constraint: the
     (K, m, ...) block is split over the mesh data axis (when a 2-d ensemble
     mesh is active — see :mod:`repro.distributed.sharding`; a no-op
@@ -70,7 +111,9 @@ def _gather_sharded(arr: jax.Array, idx: jax.Array, section_ndim: int) -> jax.Ar
     return lc(out, logical)
 
 
-def _shard_round_idx(idx: jax.Array) -> jax.Array:
+def _shard_round_idx(idx):
+    if isinstance(idx, Window):
+        return Window(lc(idx.start, ("ensemble_chains",)), idx.size)
     return lc(idx, ("ensemble_chains", "subsample"))
 
 
@@ -446,7 +489,8 @@ def build_target(
     data_fn = data if callable(data) else (lambda theta: data)
     params_fn = params_fn or (lambda theta: theta)
 
-    if log_local is None:
+    local_window = log_local is None
+    if local_window:
 
         def log_local(theta, theta_p, idx):
             return fam.delta(data_fn(theta), params_fn(theta), params_fn(theta_p), idx)
@@ -470,5 +514,6 @@ def build_target(
         log_density=log_density,
         log_local_ensemble=log_local_ensemble,
         family=fam.name,
+        local_window=local_window,
         spec=spec,
     )

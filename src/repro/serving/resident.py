@@ -321,7 +321,11 @@ class ResidentEnsemble:
         children time its host stages: ``refresh.keys`` (step keys),
         ``refresh.dispatch`` (the run's dispatch), ``refresh.wait`` (until
         the device is done), ``refresh.pull`` (draws and infos to the host)
-        and ``refresh.commit``.
+        and ``refresh.commit``. The block also carries
+        :meth:`~repro.core.ensemble.ChainEnsemble.section_tags`: whether its
+        rounds read each chain's sections as one window (``sections``), and
+        how many of its ``rounds`` fell back to the index gather
+        (``gather_rounds``).
         """
         n = self.refresh_steps if num_steps is None else int(num_steps)
         if n < 1:
@@ -345,6 +349,8 @@ class ResidentEnsemble:
             with _stage(tracer, "refresh.pull", block):
                 draws = _window_append(self._draws, samples, self.window)
                 last_infos = jax.tree.map(np.asarray, infos)
+                if block is not None:
+                    block.update(self.ensemble.section_tags(last_infos))
             with _stage(tracer, "refresh.commit", block), self._lock:
                 self._draws = draws
                 self._last_infos = last_infos

@@ -1,5 +1,7 @@
 """Multi-chain ensemble engine: vmap-vs-sequential equivalence, cross-chain
 diagnostics, batched sampler properties, multi-device fan-out."""
+import dataclasses
+import functools
 import os
 import subprocess
 import sys
@@ -14,7 +16,9 @@ from hypothesis import strategies as st
 from repro.core import (
     ChainEnsemble,
     RandomWalk,
+    ScheduleConfig,
     SubsampledMHConfig,
+    SubsampledMHInfo,
     ensemble_summary,
     fy_draw,
     fy_init,
@@ -216,3 +220,135 @@ print(json.dumps({
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["n_devices"] == 4
     assert res["max_diff"] < 1e-5, res
+
+
+# ---------------------------------------------------------------------------
+# Stream windows: each chain's round read as one slice, bit for bit
+# ---------------------------------------------------------------------------
+
+# N not a multiple of m, and a small epsilon: some chains exhaust the pool,
+# so the round whose run crosses its end takes the index gather.
+_STREAM_N, _STREAM_M = 1030, 100
+
+
+@functools.lru_cache(maxsize=None)
+def _stream_target():
+    from repro.experiments import bayeslr
+
+    data = bayeslr.synth_2d(jax.random.key(0), n=_STREAM_N)
+    return bayeslr.make_target(data.x_train, data.y_train)
+
+
+def _index_twin(target):
+    """``target`` with every Window handed back as its index block: the
+    same rounds, read through the element gather."""
+    from repro.core.target import Window
+
+    def as_idx(sec):
+        if isinstance(sec, Window):
+            return sec.start[..., None] + jnp.arange(sec.size, dtype=jnp.int32)
+        return sec
+
+    local, fused = target.log_local, target.log_local_ensemble
+    return dataclasses.replace(
+        target,
+        log_local=lambda a, b, sec: local(a, b, as_idx(sec)),
+        log_local_ensemble=lambda a, b, sec: fused(a, b, as_idx(sec)),
+    )
+
+
+def _assert_runs_equal(a, b, *, same_program=True):
+    """Samples and every info field bit for bit. Across different programs
+    (the batched scan against ``run_chain``) ``pvalue`` is compared to f32
+    tolerance: XLA fuses the betainc tail differently there, which moves
+    its last ulp without touching any decision."""
+    (s_a, i_a), (s_b, i_b) = a, b
+    np.testing.assert_array_equal(np.asarray(s_a), np.asarray(s_b))
+    for field in SubsampledMHInfo._fields:
+        got, want = np.asarray(getattr(i_a, field)), np.asarray(getattr(i_b, field))
+        if field == "pvalue" and not same_program:
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-30)
+        else:
+            np.testing.assert_array_equal(got, want, err_msg=field)
+
+
+@pytest.mark.parametrize("adaptive", [False, True], ids=["fixed", "adaptive"])
+@pytest.mark.parametrize("fused", ["never", "always", "interpret"])
+@pytest.mark.parametrize("stepping", ["lockstep", "masked"])
+def test_stream_window_rounds_bit_for_bit(stepping, fused, adaptive, monkeypatch):
+    """A ``stream`` ensemble whose rounds read windows equals the same
+    ensemble reading index blocks, and K sequential ``run_chain`` calls (the
+    vmapped single-chain scan, when adaptive), in samples and every info
+    field (``pvalue`` to f32 tolerance across programs); on runs where the
+    gather branch runs too."""
+    if fused == "interpret":  # the Pallas kernel, in interpret mode
+        monkeypatch.setenv("REPRO_FUSED", "always")
+    target = _stream_target()
+    cfg = SubsampledMHConfig(batch_size=_STREAM_M, epsilon=0.01, sampler="stream")
+    sched = ScheduleConfig() if adaptive else None
+    K, T = 3, 24
+    keys = jax.random.split(jax.random.key(5), K)
+    # shard=("chains", "data") on one device: the batched lock-step scan
+    kw = dict(config=cfg, stepping=stepping, schedule=sched, shard=("chains", "data"),
+              fused_kernels="never" if fused == "never" else "always")
+    ens = ChainEnsemble(target, RandomWalk(0.1), K, **kw)
+    twin = ChainEnsemble(_index_twin(target), RandomWalk(0.1), K, **kw)
+    _, s, infos = ens.run(keys, ens.init(jnp.zeros(2)), T)
+    _, s_twin, i_twin = twin.run(keys, twin.init(jnp.zeros(2)), T)
+    tags = ens.section_tags(jax.tree.map(np.asarray, infos))
+    assert tags["sections"] == "window"
+    assert 0 < tags["gather_rounds"] < tags["rounds"], tags
+    _assert_runs_equal((s, infos), (s_twin, i_twin))
+    if fused == "interpret":
+        return
+    if adaptive:
+        seq = ChainEnsemble(target, RandomWalk(0.1), K, config=cfg, schedule=sched,
+                            fused_kernels="never", shard=False)
+        _, s_seq, i_seq = seq.run(keys, seq.init(jnp.zeros(2)), T)
+        _assert_runs_equal((s, infos), (s_seq, i_seq), same_program=False)
+        return
+    for k in range(K):
+        _, s_seq, i_seq = run_chain(keys[k], jnp.zeros(2), target, RandomWalk(0.1), T,
+                                    config=cfg)
+        _assert_runs_equal((s[k], jax.tree.map(lambda l: l[k], infos)), (s_seq, i_seq),
+                           same_program=False)
+
+
+@pytest.mark.parametrize("adaptive", [False, True], ids=["fixed", "adaptive"])
+@pytest.mark.parametrize("stepping", ["lockstep", "masked"])
+def test_section_tags_count_the_gather_rounds_the_program_takes(stepping, adaptive):
+    """``section_tags`` derives a block's gather-branch rounds from its
+    infos; the count equals the rounds in which the program handed the
+    family an index block, counted on the device."""
+    from repro.core.target import Window
+
+    target = _stream_target()
+    taken = []
+    fused = target.log_local_ensemble
+
+    def counting(a, b, sec):
+        if not isinstance(sec, Window):
+            jax.debug.callback(lambda: taken.append(1))
+        return fused(a, b, sec)
+
+    cfg = SubsampledMHConfig(batch_size=_STREAM_M, epsilon=0.01, sampler="stream")
+    ens = ChainEnsemble(dataclasses.replace(target, log_local_ensemble=counting),
+                        RandomWalk(0.1), 4, config=cfg, stepping=stepping,
+                        schedule=ScheduleConfig() if adaptive else None,
+                        fused_kernels="always")
+    _, _, infos = ens.run(jax.random.key(3), ens.init(jnp.zeros(2)), 30)
+    jax.effects_barrier()
+    tags = ens.section_tags(jax.tree.map(np.asarray, infos))
+    assert tags["sections"] == "window"
+    assert tags["gather_rounds"] == len(taken) > 0
+    rounds = np.asarray(infos.rounds)
+    assert tags["rounds"] == (rounds.sum(axis=1).max() if stepping == "masked"
+                              else rounds.max(axis=0).sum())
+
+
+def test_section_tags_name_the_gather_where_no_window_is_read(gaussian_target_factory):
+    target, _, _ = gaussian_target_factory(n=600, seed=1)
+    fy = ChainEnsemble(target, RandomWalk(0.05), 2,
+                       config=SubsampledMHConfig(batch_size=50, epsilon=0.05))
+    _, _, infos = fy.run(jax.random.key(0), fy.init(jnp.zeros(())), 4)
+    assert fy.section_tags(infos) == {"sections": "gather"}

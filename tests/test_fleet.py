@@ -600,6 +600,43 @@ print(json.dumps({
 
 
 @pytest.mark.slow
+def test_2d_sharded_stream_windows_bit_for_bit():
+    """``stream`` rounds that read windows (and the index gather in the round
+    that reaches the pool's end) under the 2-d mesh == unsharded, lock-step
+    and masked, through the vmapped and the fused family route."""
+    script = r"""
+import json
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import ChainEnsemble, RandomWalk, SubsampledMHConfig
+from repro.experiments import bayeslr
+
+data = bayeslr.synth_2d(jax.random.key(0), n=1030)
+target = bayeslr.make_target(data.x_train, data.y_train)
+cfg = SubsampledMHConfig(batch_size=100, epsilon=0.01, sampler="stream")
+keys = jax.random.split(jax.random.key(5), 8)
+out = {"n_devices": len(jax.devices())}
+for stepping in ("lockstep", "masked"):
+    for fused in ("never", "always"):
+        runs = {}
+        for name, shard in (("2d", ("chains", "data")), ("off", False)):
+            ens = ChainEnsemble(target, RandomWalk(0.1), 8, config=cfg, shard=shard,
+                                stepping=stepping, fused_kernels=fused)
+            _, s, i = ens.run(keys, ens.init(jnp.zeros(2)), 24)
+            runs[name] = (np.asarray(s), np.asarray(i.n_evaluated), np.asarray(i.rounds))
+            if name == "2d":
+                tags = ens.section_tags(jax.tree.map(np.asarray, i))
+        out[f"{stepping}-{fused}"] = bool(
+            all(np.array_equal(a, b) for a, b in zip(runs["2d"], runs["off"]))
+            and tags["sections"] == "window" and tags["gather_rounds"] > 0)
+print(json.dumps(out))
+"""
+    res = _run_forced_devices(script)
+    assert res.pop("n_devices") == 4
+    assert res == {f"{s}-{f}": True for s in ("lockstep", "masked")
+                   for f in ("never", "always")}
+
+
+@pytest.mark.slow
 def test_sharded_fleet_checkpoint_roundtrip_at_4_devices(tmp_path):
     """A fleet whose writers run the 2-d mesh checkpoints and restores
     warm: the restored key schedule continues bit for bit and the replicas

@@ -1,4 +1,6 @@
 """Kernel-family registry / target builder and the composite cycle engine."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -301,3 +303,133 @@ def test_lockstep_fused_with_schedule_stays_in_bounds():
     assert set(np.unique(np.asarray(infos.batch_eff)).tolist()) <= set(
         sched.buckets_for(cfg, 600))
     assert np.asarray(state.controller.t).tolist() == [50, 50, 50]
+
+# ---------------------------------------------------------------------------
+# Stream windows: a round's contiguous sections read as one slice per chain
+# ---------------------------------------------------------------------------
+
+
+def _family_case(name, k, n=40):
+    """(data, params, params_p, section_ndims) of a registered family, with
+    a (k,) chain axis on the params."""
+    ks = jax.random.split(jax.random.key(21), 6)
+    if name == "logit":
+        data = _logit_data(n=n, d=3, seed=2)
+        p = [0.3 * jax.random.normal(kk, (k, 3)) for kk in ks[:2]]
+        return data, p[0], p[1], (1, 0)
+    if name == "gaussian_ar1":
+        data = tuple(jax.random.normal(kk, (n,)) for kk in ks[:2])
+        params = lambda kk: (0.2 + 0.5 * jax.random.uniform(kk, (k,)),
+                             0.5 + jax.random.uniform(jax.random.fold_in(kk, 1), (k,)))
+        return data, params(ks[2]), params(ks[3]), (0, 0)
+    if name == "ce":
+        data = (0.3 * jax.random.normal(ks[0], (n, 8)),
+                jax.random.randint(ks[1], (n,), 0, 30))
+        tab = lambda kk: 0.3 * jax.random.normal(kk, (k, 30, 8))
+        return data, tab(ks[2]), tab(ks[3]), (1, 0)
+    assert name == "gaussian_mean"
+    data = jax.random.normal(ks[0], (n, 2))
+    return data, jax.random.normal(ks[2], (k, 2)), jax.random.normal(ks[3], (k, 2)), (1,)
+
+
+@pytest.mark.parametrize("pool", ["shared", "per_chain"])
+@pytest.mark.parametrize("name", ["logit", "gaussian_ar1", "ce", "gaussian_mean"])
+def test_window_reads_the_same_sections_as_its_index_block(name, pool):
+    """A Window equals the index block it stands for, bit for bit: the
+    gather of every pool leaf, the fused (K, m) round and the vmapped
+    single-chain delta of every registered family."""
+    from repro.core.target import Window
+    from repro.core.target_builder import _gather
+
+    assert set(registered_families()) == {"logit", "gaussian_ar1", "ce", "gaussian_mean"}
+    k, m, n = 3, 9, 40
+    data, p, p_prop, ndims = _family_case(name, k, n)
+    if pool == "per_chain":  # each chain its own (rotated) copy of the pool
+        data = jax.tree.map(
+            lambda a: jnp.stack([jnp.roll(a, 5 * c, axis=0) for c in range(k)]), data)
+    starts = jnp.asarray([0, 17, n - m], jnp.int32)
+    window = Window(starts, m)
+    idx = starts[:, None] + jnp.arange(m, dtype=jnp.int32)
+    for leaf, ndim in zip(jax.tree.leaves(data), ndims):
+        np.testing.assert_array_equal(np.asarray(_gather(leaf, window, ndim)),
+                                      np.asarray(_gather(leaf, idx, ndim)))
+    fam = get_family(name)
+    np.testing.assert_array_equal(
+        np.asarray(fam.ensemble_delta(data, p, p_prop, window)),
+        np.asarray(fam.ensemble_delta(data, p, p_prop, idx)))
+    axes = (0 if pool == "per_chain" else None, 0, 0, 0)
+    vmapped = jax.vmap(fam.delta, in_axes=axes)
+    np.testing.assert_array_equal(
+        np.asarray(vmapped(data, p, p_prop, window)),
+        np.asarray(vmapped(data, p, p_prop, idx)))
+
+
+def test_only_builder_closures_read_windows():
+    """The builder marks its own ``log_local`` as reading windows; a
+    hand-wired one (the PPL's graph evaluator, say) keeps index blocks."""
+    x, y = _logit_data(n=50)
+    prior = lambda w: -0.5 * jnp.sum(w**2)
+    assert build_target("logit", (x, y), 50, prior_logpdf=prior).local_window
+    hand = build_target("logit", (x, y), 50, prior_logpdf=prior,
+                        log_local=lambda a, b, idx: jnp.zeros(idx.shape))
+    assert not hand.local_window and hand.log_local_ensemble is not None
+
+
+_CALLEES = (r"(?:calls|to_apply|body|condition|branch_computations)="
+            r"(?:\{([^}]*)\}|%?([\w.\-]+))")
+
+
+def _pool_reads(hlo: str, root: str) -> list[tuple]:
+    """The reads in computation ``root`` of compiled HLO text and in the
+    computations it calls: ``("gather", index rows, slice_sizes)`` and
+    ``("dynamic-slice", sizes)``."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    out, seen, todo = [], set(), [root]
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            for group, single in re.findall(_CALLEES, line):
+                todo += [c.strip().lstrip("%") for c in (group or single).split(",")]
+            sizes = lambda text: tuple(int(v) for v in text.split(","))
+            g = re.search(r"=\s*\w+\[(\d+)[\d,]*\][^=]*\sgather\(.*slice_sizes=\{([\d,]*)\}",
+                          line)
+            if g:
+                out.append(("gather", int(g.group(1)), sizes(g.group(2))))
+            d = re.search(r"\sdynamic-slice\(.*dynamic_slice_sizes=\{([\d,]*)\}", line)
+            if d:
+                out.append(("dynamic-slice", sizes(d.group(1))))
+    return out
+
+
+@pytest.mark.parametrize("fused", ["never", "always"])
+def test_stream_round_reads_one_window_per_chain(fused):
+    """At the refresh cell's shape (K = 8, m = 500, N = 12,214, D = 50) the
+    window branch of a lock-step ``stream`` round reads the pool as K slices
+    of m sections, for the rows (m, D) and the labels (m,), and gathers
+    nothing; the element gathers (K * m index rows of one section) stay in
+    the other branch, the round where a chain reaches the pool's end."""
+    n, d, k, m = 12214, 50, 8, 500
+    x, y = _logit_data(n=n, d=d, seed=3)
+    t = build_target("logit", (x, y), n, prior_logpdf=lambda w: -5.0 * jnp.sum(w**2))
+    cfg = SubsampledMHConfig(batch_size=m, epsilon=0.05, sampler="stream")
+    ens = ChainEnsemble(t, RandomWalk(0.05), k, config=cfg, fused_kernels=fused,
+                        shard=("chains", "data"))  # one device: the batched scan
+    state = ens.init(jnp.zeros(d))
+    hlo = ens.lower(state, 1, step_keys=ens.step_keys(jax.random.key(0), 0, 1)
+                    ).compile().as_text()
+    (branches,) = re.findall(
+        r"\sconditional\(.*branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}", hlo)
+    on_index, on_window = (sorted(_pool_reads(hlo, b)) for b in branches)
+    assert on_window == sorted([("dynamic-slice", (m,))] * k
+                               + [("dynamic-slice", (m, d))] * k), on_window
+    assert on_index == [("gather", k * m, (1,)), ("gather", k * m, (1, d))], on_index

@@ -409,6 +409,35 @@ def test_untraced_refresh_builds_no_span_and_opens_no_annotation(
     assert names == ["repro.refresh", *("repro." + s for s in REFRESH_STAGES)]
 
 
+@pytest.mark.parametrize("case", ["exhausting", "inside"])
+def test_refresh_block_tags_its_window_and_gather_rounds(case):
+    """A ``stream`` ensemble's refresh blocks carry ``sections="window"`` and
+    the rounds that fell back to the index gather: some where chains read to
+    the end of the pool (N = 1,030 is no multiple of m = 100), none where
+    every test stops after a round or two (a tiny proposal step)."""
+    import jax.numpy as jnp
+
+    from repro.core import ChainEnsemble, RandomWalk, SubsampledMHConfig
+    from repro.experiments import bayeslr
+    from repro.serving.resident import ResidentEnsemble
+
+    data = bayeslr.synth_2d(jax.random.key(0), n=1030)
+    target = bayeslr.make_target(data.x_train, data.y_train)
+    sigma, eps = (0.3, 1e-3) if case == "exhausting" else (1e-4, 0.1)
+    cfg = SubsampledMHConfig(batch_size=100, epsilon=eps, sampler="stream")
+    ens = ChainEnsemble(target, RandomWalk(sigma), 3, config=cfg, stepping="masked")
+    tracer = Tracer()
+    resident = ResidentEnsemble(ens, jnp.zeros(2), key=jax.random.key(1), window=8,
+                                refresh_steps=8, tracer=tracer)
+    resident.refresh()
+    resident.refresh()
+    blocks = _blocks(tracer.spans())
+    assert [b["sections"] for b in blocks] == ["window", "window"]
+    assert all(0 <= b["gather_rounds"] < b["rounds"] for b in blocks)
+    gathered = sum(b["gather_rounds"] for b in blocks)
+    assert gathered > 0 if case == "exhausting" else gathered == 0
+
+
 def test_evaluator_splits_device_eval_into_upload_and_run(traced_pool):
     from repro.serving.resident import SnapshotEvaluator
 
