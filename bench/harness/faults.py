@@ -12,14 +12,20 @@ the tests under ``bench/tests``.
   products are bit for bit this path's); for serving, the reference's
   functionals computed with bfloat16 operands in the evaluator's place.
 * ``frozen``: a refresh that returns its state unchanged (the chains
-  never move, the infos still come from a real block).
+  never move, every leaf of the draws holds the collected state, the
+  infos still come from a real block).
 * ``half``: half of the batch left out, the mean taken over the rest. For
   refresh, the second half of every round's deltas replaced by the first
-  half's mean; for serving, the functional averaged over the first half
-  of the snapshot's draws.
-* ``altered_draw``: one coordinate of every fourth draw shifted by a
-  thousandth of the proposal scale where the refresh produces them (the
-  chains themselves go on from their true states).
+  half's mean, in every batched delta of ``repro.kernels.ops``
+  (``batched_<family>_delta``), so whichever family a model's operators
+  evaluate; for serving, the functional averaged over the first half of
+  the snapshot's draws.
+* ``altered_draw``: in every leaf of the draws, the first coordinate of
+  every fourth draw shifted by a thousandth of that leaf's proposal scale
+  where the refresh produces them (the chains themselves go on from their
+  true states). The scale is the model module's ``draw_scale(cfg)`` (one
+  number, or a dict by the leaves of the collected draws), else
+  ``cfg["builder"]["sigma"]``.
 * ``altered_answer``: every served value shifted by 1e-3.
 """
 from __future__ import annotations
@@ -58,20 +64,33 @@ def _bf16_refresh():
 
 
 def _frozen_run(orig):
+    import jax
     import jax.numpy as jnp
 
     def run(self, key, state, num_steps, *, step_keys=None):
         _, samples, infos = orig(self, key, state, num_steps, step_keys=step_keys)
-        stuck = jnp.broadcast_to(state.theta[:, None], samples.shape)
+        held = state.theta if self.collect is None else jax.vmap(self.collect)(state.theta)
+        stuck = jax.tree.map(lambda h, s: jnp.broadcast_to(h[:, None], s.shape),
+                             held, samples)
         return state, stuck, infos
 
     return run
 
 
-def _altered_run(orig, sigma):
+def _shifted(leaf, scale):
+    """``leaf`` (K, T, ...) with the first coordinate of every fourth draw
+    moved by a thousandth of ``scale``."""
+    return leaf.at[(slice(None), slice(None, None, 4)) + (0,) * (leaf.ndim - 2)].add(
+        1e-3 * scale)
+
+
+def _altered_run(orig, scale):
+    import jax
+
     def run(self, key, state, num_steps, *, step_keys=None):
         new, samples, infos = orig(self, key, state, num_steps, step_keys=step_keys)
-        return new, samples.at[:, ::4, 0].add(1e-3 * sigma), infos
+        scales = scale if isinstance(scale, dict) else jax.tree.map(lambda _: scale, samples)
+        return new, jax.tree.map(_shifted, samples, scales), infos
 
     return run
 
@@ -79,13 +98,23 @@ def _altered_run(orig, sigma):
 def _half_deltas(orig):
     import jax.numpy as jnp
 
-    def delta(xg, yg, w_cur, w_prop, **kw):
-        l = orig(xg, yg, w_cur, w_prop, **kw)
+    def delta(*args, **kw):
+        l = orig(*args, **kw)  # (K, m)
         h = l.shape[1] // 2
         first = jnp.mean(l[:, :h], axis=1, keepdims=True)
         return l.at[:, h:].set(jnp.broadcast_to(first, l[:, h:].shape))
 
     return delta
+
+
+@contextlib.contextmanager
+def _half_every_delta(ops):
+    """``_half_deltas`` on every ``batched_<family>_delta`` of ``ops``."""
+    with contextlib.ExitStack() as stack:
+        for name in dir(ops):
+            if name.startswith("batched_") and name.endswith("_delta"):
+                stack.enter_context(_patch(ops, name, _half_deltas(getattr(ops, name))))
+        yield
 
 
 def _evaluate_with(values_fn):
@@ -111,7 +140,7 @@ def planted(fault: str | None, model, cfg: dict, kind: str):
     from repro.kernels import ops
     from repro.serving.resident import SnapshotEvaluator
 
-    ref = model.reference_values
+    ref = getattr(model, "reference_values", None)
     serve = kind == "open_loop"
     if fault == "control" and not serve:
         ctx = _bf16_refresh()
@@ -121,13 +150,14 @@ def planted(fault: str | None, model, cfg: dict, kind: str):
     elif fault == "frozen":
         ctx = _patch(ChainEnsemble, "run", _frozen_run(ChainEnsemble.run))
     elif fault == "half" and not serve:
-        ctx = _patch(ops, "batched_logit_delta", _half_deltas(ops.batched_logit_delta))
+        ctx = _half_every_delta(ops)
     elif fault == "half":
         ctx = _patch(SnapshotEvaluator, "evaluate", _evaluate_with(
             lambda cls, xs, d: ref(cls, xs, d[: d.shape[0] // 2])))
     elif fault == "altered_draw":
-        ctx = _patch(ChainEnsemble, "run",
-                     _altered_run(ChainEnsemble.run, cfg["builder"]["sigma"]))
+        scale = (model.draw_scale(cfg) if hasattr(model, "draw_scale")
+                 else cfg["builder"]["sigma"])
+        ctx = _patch(ChainEnsemble, "run", _altered_run(ChainEnsemble.run, scale))
     elif fault == "altered_answer" and serve:
         ctx = _patch(SnapshotEvaluator, "evaluate", _evaluate_with(
             lambda cls, xs, d: ref(cls, xs, d) + 1e-3))

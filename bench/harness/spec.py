@@ -10,6 +10,18 @@ Layout under the checkout root::
     bench/metrics/<metric>.py      one reader per metric
 
 A new cell is new files plus a ``workloads`` entry: nothing here changes.
+
+A model module must define ``make_data(cfg, data_seed)``,
+``build_pool(cfg, data, program_seed)`` and, per traffic kind it serves,
+``check_refresh`` (kind ``refresh``) or ``check_serve`` and
+``check_chains`` (kind ``open_loop``, whose control in
+``bench/harness/faults.py`` also reads ``reference_values``). For
+``refresh`` it may also define ``sizes(cfg)`` and
+``after_block(resident, block_index, rng)``; ``bench/harness/refresh.py``
+says what each is given and what the ``rec`` handed to ``check_refresh``
+holds, for a single operator and for a composite cycle. It may define
+``draw_scale(cfg)``, each draw leaf's proposal scale, by which the
+``altered_draw`` fault shifts a draw (else ``cfg["builder"]["sigma"]``).
 """
 from __future__ import annotations
 
